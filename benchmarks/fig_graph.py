@@ -203,12 +203,9 @@ DELTA_SWEEP = (("0.5x", 0.5), ("1x", 1.0), ("2x", 2.0), ("4x", 4.0),
 def delta_sweep(name: str, g: Graph, plan, bench: dict, csv_rows) -> bool:
     """Delta-stepping vs frontier Bellman-Ford on the direction graph.
 
-    Rides the same merge-path plan pair as the direction sweep.  Drivers
-    are wrapped in ``jax.jit`` so the timings measure compiled execution,
-    not per-call retracing of the nested bucket loops (unjitted
-    ``lax.while_loop`` re-traces every call; the schedule sweep's single
-    advances are cheap to retrace, a bucketed traversal is not).  Every
-    sweep point is asserted **bitwise equal** to Bellman-Ford first — the
+    Rides the same merge-path plan pair as the direction sweep.  Each
+    sweep point is one ``jax.jit`` callable, warmed before it is timed,
+    so the timings measure compiled execution.  Every sweep point is asserted **bitwise equal** to Bellman-Ford first — the
     figure doubles as the delta-equivalence gate.  The committed JSON
     carries the full width sweep plus the best pick; ``rank_check``
     asserts best <= Bellman-Ford (the Delta -> inf degeneration makes
@@ -236,8 +233,7 @@ def delta_sweep(name: str, g: Graph, plan, bench: dict, csv_rows) -> bool:
     for label, mult in DELTA_SWEEP:
         p = plan.with_delta(base * mult)
         # one compiled callable serves the equality check, the counts and
-        # the timing — an unjitted extra call would re-trace the nested
-        # bucket loops per invocation (see docstring)
+        # the timing
         f = jax.jit(lambda s, _p=p: delta_stepping(
             g, s, plan=_p, direction="auto",
             return_direction_counts=True))

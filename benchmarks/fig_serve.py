@@ -7,13 +7,14 @@ single-query drivers on a mixed BFS/SSSP arrival stream:
   shared plan pair, retire-and-backfill, exactly one trace of the jitted
   serving step for the whole stream (asserted).
 * **sequential**: the shipped single-query path — one driver call per
-  query.  Each eager call re-traces its fresh loop closures, which is
-  precisely the cost the serving layer's no-retrace contract removes.
-* **sequential_precompiled**: the best-case hand-rolled baseline — a
-  ``jax.jit`` wrapper per (kind, graph, plan) compiled once, then called
-  per query.  Recorded for honesty but not rank-gated: on the CPU bench
-  harness vmapped lanes serialize, so batching's win over this baseline
-  is dispatch amortization only (a real-accelerator trajectory number).
+  query.  The drivers compile their loop once per plan and reuse it for
+  every source.
+* **sequential_precompiled**: a ``jax.jit`` wrapper per (kind, graph,
+  plan) compiled once, then called per query.
+
+The three rates are recorded, not rank-gated: on the CPU bench harness
+vmapped lanes run one after another, so which path wins is a question for
+a chip benchmark.
 
 Latency percentiles (p50/p99, submit-to-retire, queueing included) come
 from the per-query timestamps every ``ServedResult`` carries.
@@ -120,7 +121,7 @@ def run(csv_rows, smoke: bool = False):
     p50 = lat_ms[len(lat_ms) // 2]
     p99 = lat_ms[min(len(lat_ms) - 1, int(np.ceil(0.99 * len(lat_ms))) - 1)]
 
-    # sequential: the shipped per-query path (re-traces per call)
+    # sequential: the shipped per-query path
     t0 = time.perf_counter()
     for kind, s in queries:
         jax.block_until_ready(
@@ -149,8 +150,7 @@ def run(csv_rows, smoke: bool = False):
         "step_traces": srv.step_traces, "admit_traces": srv.admit_traces,
         "mixed_bitwise": mixed_ok,
     }
-    ok = (mixed_ok and one_trace
-          and serving["batched_qps"] >= serving["sequential_qps"])
+    ok = mixed_ok and one_trace
 
     # merge (never clobber) into the fig_graph-owned JSON
     out_dir = os.environ.get("REPRO_BENCH_DIR")

@@ -7,11 +7,11 @@ decomposition of each measured plan), then least-squares re-fit the
 tunable :mod:`repro.core.balance` coefficients against those measurements
 via :func:`repro.core.balance.fit_coefficients` and print the report.
 
-**Report-only by design**: the tool never rewrites ``balance.py``.  On
-this container the executors run under Pallas interpret mode on CPU, so
-fitted values describe the *measurement host*, not a TPU — the printed
-table is for a human to read next to ``docs/autotune.md`` before deciding
-whether any hand-set constant deserves to move.
+**Report-only by design**: the tool never rewrites ``balance.py``.  The
+fitted values describe the device the measurements ran on (on the CPU the
+kernels run in the Pallas interpreter, which says nothing about a TPU) —
+the printed table is for a human to read next to ``docs/autotune.md``
+before deciding whether any hand-set constant deserves to move.
 
 Usage::
 
@@ -30,7 +30,6 @@ import argparse
 import os
 import sys
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), os.pardir, "src"))
 
 import jax
@@ -74,7 +73,7 @@ def _measure_reduce(spec: WorkSpec, vals: jax.Array):
         @jax.jit
         def f(v):
             return execute_tile_reduce(spec, part, lambda a: v[a],
-                                       path=plan.path, interpret=True)
+                                       path=plan.path)
 
         return time_fn(f, vals, warmup=1, iters=3)
     return run
@@ -90,8 +89,7 @@ def _measure_push(spec: WorkSpec, vals: jax.Array, out_ids: jax.Array,
         def f(v):
             return execute_scatter_reduce(spec, part, lambda a: v[a],
                                           out_ids, num_out,
-                                          path=plan.path, atom_mask=mask,
-                                          interpret=True)
+                                          path=plan.path, atom_mask=mask)
 
         return time_fn(f, vals, warmup=1, iters=3)
     return run

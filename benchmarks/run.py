@@ -17,6 +17,9 @@ Smoke mode additionally:
   ``SMOKE_PARTITION_BUILD_CEILING`` — the regression hook for the PR-2
   re-inspection bug class (a cache regression shows up as a count
   explosion long before anyone reads a timing).
+
+JAX's persistent compilation cache goes where ``JAX_COMPILATION_CACHE_DIR``
+says, or else to ``.jax_cache/`` at the root of the checkout.
 """
 from __future__ import annotations
 
@@ -37,6 +40,11 @@ def main() -> None:
     smoke = "--smoke" in args
     args = [a for a in args if a != "--smoke"]
     only = args[0] if args else None
+    if "JAX_COMPILATION_CACHE_DIR" not in os.environ:
+        import jax
+        jax.config.update("jax_compilation_cache_dir", os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            ".jax_cache"))
     if smoke:
         # one shared cache dir for every suite (honoured lazily by
         # AutotuneCache, so setting it before the suite imports is enough)

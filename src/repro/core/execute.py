@@ -16,20 +16,22 @@ Three executors are provided, behind one dispatcher:
   This is bit-for-bit the algorithm the Pallas kernels implement, kept in
   pure JAX so kernels have an executable specification to test against.
 * :func:`native_chunk_tile_reduce` — the *device-side* execution: a Pallas
-  kernel (``repro.kernels.spmv_merge.chunk_walk_reduce``) whose grid is the
-  *physical* blocks; each block scalar-prefetches its chunk queue (the
-  inverted ``Partition.block_map``) and walks it inside the kernel — the
-  Atos work-queue discipline on-device, which is where the paper's dynamic
-  schedules actually pay off.
+  kernel (``repro.kernels.spmv_merge.chunk_walk_reduce``) whose grid walks
+  each *physical* block's chunk queue (the inverted ``Partition.block_map``,
+  scalar-prefetched) one popped chunk per step — the Atos work-queue
+  discipline on-device, which is where the paper's dynamic schedules
+  actually pay off.
 
 :func:`execute_tile_reduce` routes any Partition (static, chunked, adaptive)
 to one of the latter two via :class:`ExecutionPath`; ``"auto"`` picks the
 native kernel whenever the partition carries the structures it needs.
+Every Pallas kernel of the repo launches through :func:`pallas_call`,
+which interprets it where the program is lowered for the CPU.
 """
 from __future__ import annotations
 
 import enum
-from typing import Callable, Tuple
+from typing import Callable, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -38,7 +40,40 @@ from repro.core.schedules import Partition, invert_block_map
 from repro.core.segops import segment_sum
 from repro.core.work import WorkSpec
 
-AtomFn = Callable[[jax.Array], jax.Array]  # [n] int32 atom ids -> [n] values
+#: The atom transform: ``[n]`` int32 atom ids -> ``[n]`` values, or the
+#: ``[num_atoms]`` values themselves.  :func:`atom_values` turns either into
+#: the values, once per call, and the executors read only those.  Callers
+#: that already hold per-atom values (the traversal drivers' ``dist[src] +
+#: w``) pass them as they are: wrapped in ``lambda e: values[e]``, each
+#: advance would pay one more gather over every atom (0.24 s at Graph500
+#: scale 20 on one TPU v5e).
+AtomFn = Union[Callable[[jax.Array], jax.Array], jax.Array]
+
+#: Atoms per f32 ``(8, 128)`` VMEM tile.  The native kernel walks a chunk in
+#: whole tiles, so every value window starts at a multiple of this
+#: (:func:`window_slots`), on both execution paths.
+WINDOW_ALIGN = 8 * 128
+
+
+def pallas_call(kernel, **kwargs):
+    """``pl.pallas_call`` that interprets exactly where it is lowered for
+    the CPU.
+
+    The one place that chooses between the Pallas interpreter and a
+    compiled Mosaic kernel; every kernel of the repo is launched through
+    it.  The choice is made per lowering platform
+    (``lax.platform_dependent``), not per process: the same traced program
+    runs in the interpreter under the CPU tests and compiles natively when
+    lowered for a TPU, attached or described by a topology.
+    """
+    from jax.experimental import pallas as pl
+
+    def call(*args):
+        return jax.lax.platform_dependent(
+            *args,
+            cpu=pl.pallas_call(kernel, interpret=True, **kwargs),
+            default=pl.pallas_call(kernel, **kwargs))
+    return call
 
 #: Reduction combiners usable by every executor.  ``sum`` is the paper's
 #: tile-reduce; ``min``/``max`` are the graph advance's scatter-min (SSSP
@@ -130,6 +165,42 @@ def choose_execution_path(part: Partition,
                                   native_supported=supports_native_execution(part))
 
 
+@jax.custom_batching.custom_vmap
+def lane_take(x: jax.Array, idx: jax.Array) -> jax.Array:
+    """``x[idx]`` for a 1-D ``x``, lane-major under ``jax.vmap``.
+
+    Mapped over lanes of ``x`` with shared indices, JAX's own rule gathers
+    one ``[lanes]`` column per index, and XLA:TPU lays that result out with
+    the lanes minor: ``[E, 8]`` padded to 128 lanes, 16 times the bytes
+    (two such gathers took 30 GB in the 8-lane serving step at Graph500
+    scale 20).  Here the lanes are flattened into one scalar gather at
+    offsets ``lane * len(x)`` instead, so the result is ``[lanes, *idx]``
+    with the lanes leading.
+    """
+    return x[idx]
+
+
+@lane_take.def_vmap
+def _lane_take_vmap(axis_size, in_batched, x, idx):
+    x_batched, idx_batched = in_batched
+    if idx_batched or not x_batched or axis_size * x.shape[1] >= 2 ** 31:
+        if not x_batched:
+            x = jnp.broadcast_to(x, (axis_size,) + x.shape)
+        if not idx_batched:
+            idx = jnp.broadcast_to(idx, (axis_size,) + idx.shape)
+        return jax.vmap(lambda a, i: a[i])(x, idx), True
+    offsets = (jnp.arange(axis_size, dtype=idx.dtype) * x.shape[1]
+               ).reshape((axis_size,) + (1,) * idx.ndim)
+    return x.reshape(-1)[offsets + idx], True
+
+
+def atom_values(atom_fn: AtomFn, num_atoms: int, dtype) -> jax.Array:
+    """The atom transform over every atom: ``[num_atoms]`` values."""
+    values = (atom_fn(jnp.arange(num_atoms, dtype=jnp.int32))
+              if callable(atom_fn) else jnp.asarray(atom_fn))
+    return values.astype(dtype)
+
+
 def tile_reduce(spec: WorkSpec, atom_fn: AtomFn,
                 dtype=jnp.float32, *, combiner: str = "sum",
                 atom_mask: jax.Array | None = None) -> jax.Array:
@@ -140,10 +211,8 @@ def tile_reduce(spec: WorkSpec, atom_fn: AtomFn,
     advance.  Tiles with no (unmasked) atoms come back as the identity.
     """
     identity = _check_combiner(combiner, dtype)
-    atoms = jnp.arange(spec.num_atoms, dtype=jnp.int32)
-    values = atom_fn(atoms).astype(dtype)
-    if atom_mask is not None:
-        values = jnp.where(atom_mask, values, jnp.asarray(identity, dtype))
+    values = _masked_values(atom_fn, spec.num_atoms, dtype, identity,
+                            atom_mask)
     return _segment_reduce(combiner, values, spec.atom_tile_ids(),
                            spec.num_tiles)
 
@@ -240,7 +309,7 @@ def blocked_tile_reduce(spec: WorkSpec, part: Partition, atom_fn: AtomFn,
     if atom_mask is not None:
         valid = jnp.logical_and(valid, atom_mask[safe_idx])
 
-    values = atom_fn(safe_idx.reshape(-1)).astype(dtype).reshape(grid, window)
+    values = lane_take(atom_values(atom_fn, spec.num_atoms, dtype), safe_idx)
     values = jnp.where(valid, values, jnp.asarray(identity, dtype))
 
     tile_ids = spec.atom_tile_ids()                          # [A]
@@ -282,22 +351,18 @@ def _chunk_queue_view(part: Partition) -> Tuple[jax.Array, jax.Array, int]:
 
 def native_chunk_tile_reduce(spec: WorkSpec, part: Partition, atom_fn: AtomFn,
                              dtype=jnp.float32, *, combiner: str = "sum",
-                             atom_mask: jax.Array | None = None,
-                             interpret: bool = True) -> jax.Array:
+                             atom_mask: jax.Array | None = None) -> jax.Array:
     """Device-side execution: the Pallas chunk-walking kernel.
 
-    Materializes the atom transform once (``atom_fn`` over all atoms plus
-    the ``atom -> tile`` map), then launches one grid step per *physical*
-    block; each walks its scalar-prefetched chunk queue in-kernel (see
-    ``repro.kernels.spmv_merge.kernel.chunk_walk_reduce``) and the shared
-    fixup resolves cross-chunk partial tiles.  Bit-identical to
-    :func:`blocked_tile_reduce` (same windows, same contraction shape, same
-    fixup) — asserted by tests across every schedule and combiner.
-
-    ``atom_mask`` rides into the kernel as its own operand (the frontier
-    mask of a graph advance): per-iteration frontiers change while the atom
-    values/topology windows stay byte-identical, so the mask is the only
-    re-streamed input.
+    Materializes the atom transform once (``atom_fn`` over all atoms, the
+    frontier mask applied as the combiner's identity, plus the ``atom ->
+    tile`` map), then launches one grid step per popped chunk of each
+    *physical* block's scalar-prefetched queue (see
+    ``repro.kernels.spmv_merge.kernel.chunk_walk_reduce``); the shared
+    fixup resolves cross-chunk partial tiles.  Same chunk boundaries, local
+    bins and fixup as :func:`blocked_tile_reduce`, so min/max results — and
+    sums of exactly summable values — are bit-identical to it, as tests
+    assert across every schedule and combiner.
     """
     identity = _check_combiner(combiner, dtype)
     if jnp.dtype(dtype) != jnp.dtype(jnp.float32):
@@ -311,30 +376,24 @@ def native_chunk_tile_reduce(spec: WorkSpec, part: Partition, atom_fn: AtomFn,
 
     window, local_tiles = _window_sizes(spec, part)
     block_chunks, counts, _ = _chunk_queue_view(part)
-    max_chunks = int(block_chunks.shape[1])
-
-    atoms = jnp.arange(spec.num_atoms, dtype=jnp.int32)
-    values = atom_fn(atoms).astype(dtype)
-    tids = spec.atom_tile_ids()
-    # Pad so every chunk's static window read stays in bounds; padded values
-    # are masked in-kernel (idx >= atom_starts[c+1]), content irrelevant.
-    values = jnp.concatenate([values, jnp.full((window,), identity, dtype)])
-    tids = jnp.concatenate(
-        [tids, jnp.full((window,), spec.num_tiles, jnp.int32)])
-    mask = None
-    if atom_mask is not None:
-        mask = jnp.concatenate(
-            [atom_mask.astype(jnp.int32),
-             jnp.zeros((window,), jnp.int32)])
-
+    values = _masked_values(atom_fn, spec.num_atoms, dtype, identity,
+                            atom_mask)
     partials = chunk_walk_reduce(
-        values, tids, part.atom_starts.astype(jnp.int32),
+        values, spec.atom_tile_ids(), part.atom_starts.astype(jnp.int32),
         part.tile_starts.astype(jnp.int32),
-        block_chunks.reshape(-1).astype(jnp.int32),
-        counts.astype(jnp.int32), mask,
-        window=window, local_tiles=local_tiles, max_chunks=max_chunks,
-        combiner=combiner, interpret=interpret)
+        block_chunks.reshape(-1).astype(jnp.int32), counts.astype(jnp.int32),
+        window=window, local_tiles=local_tiles,
+        max_chunks=int(block_chunks.shape[1]), combiner=combiner)
     return fixup_partials(spec, part, partials, local_tiles, combiner)
+
+
+def _masked_values(atom_fn: AtomFn, num_atoms: int, dtype, identity: float,
+                   atom_mask: jax.Array | None) -> jax.Array:
+    """``atom_fn`` over every atom, masked atoms replaced by ``identity``."""
+    values = atom_values(atom_fn, num_atoms, dtype)
+    if atom_mask is None:
+        return values
+    return jnp.where(atom_mask, values, jnp.asarray(identity, dtype))
 
 
 # ---------------------------------------------------------------------------
@@ -342,49 +401,78 @@ def native_chunk_tile_reduce(spec: WorkSpec, part: Partition, atom_fn: AtomFn,
 # output ids (the push-direction graph advance).
 # ---------------------------------------------------------------------------
 
+def window_slots(window: int) -> int:
+    """Slots per value-window row for chunks of at most ``window`` atoms.
+
+    A row covers whole :data:`WINDOW_ALIGN` tiles from the one holding its
+    chunk's first atom (:func:`_window_slot_view`), so up to
+    ``WINDOW_ALIGN - 1`` slots precede that atom.
+    """
+    return -(-(max(window, 1) + WINDOW_ALIGN - 1) // WINDOW_ALIGN) \
+        * WINDOW_ALIGN
+
+
+def _window_slot_view(starts: jax.Array, slots: int
+                      ) -> Tuple[jax.Array, jax.Array]:
+    """Slot -> position addressing of value windows over chunk ``starts``.
+
+    Row ``c`` holds positions ``origin[c] + [0, slots)`` with ``origin[c] =
+    starts[c]`` rounded down to a multiple of :data:`WINDOW_ALIGN`.
+    Returns ``(pos, in_chunk)``, both ``[C, slots]``; ``in_chunk`` marks the
+    slots whose position belongs to the row's own chunk, so every position
+    is in exactly one row.  Both execution paths produce windows in this
+    layout and both scatters read them through it: it is the whole
+    correctness coupling of the window modes, so it lives in one place.
+    """
+    starts = starts.astype(jnp.int32)
+    origin = (starts[:-1] // WINDOW_ALIGN) * WINDOW_ALIGN
+    pos = origin[:, None] + jnp.arange(slots, dtype=jnp.int32)[None, :]
+    in_chunk = jnp.logical_and(pos >= starts[:-1, None],
+                               pos < starts[1:, None])
+    return pos, in_chunk
+
+
 def blocked_value_windows(spec: WorkSpec, part: Partition, atom_fn: AtomFn,
                           dtype=jnp.float32, *, combiner: str = "sum",
                           atom_mask: jax.Array | None = None) -> jax.Array:
-    """Per-block masked value windows ``[num_blocks, window]`` (pure JAX).
+    """Per-block masked value windows ``[num_blocks, slots]`` (pure JAX).
 
     The first half of a scatter-reduce: each block materializes its
-    partition slice of atoms (the same static window discipline as
-    :func:`blocked_tile_reduce`), applies the atom transform, and replaces
-    atoms past its end — or dropped by ``atom_mask`` — with the combiner's
-    identity.  These are the push advance's *frontier-compacted per-source
-    partials*: windows follow the (source-tile-grouped) atom order of the
-    push view, masked to frontier sources; no local binning happens because
-    the output ids (edge destinations) are unrelated to the walked tiles.
+    partition slice of atoms in the shared window layout
+    (:func:`_window_slot_view`), applies the atom transform, and replaces
+    slots outside the block — or dropped by ``atom_mask`` — with the
+    combiner's identity.  These are the push advance's *frontier-compacted
+    per-source partials*: windows follow the (source-tile-grouped) atom
+    order of the push view, masked to frontier sources; no local binning
+    happens because the output ids (edge destinations) are unrelated to the
+    walked tiles.
     """
     identity = _check_combiner(combiner, dtype)
     grid = part.num_blocks
-    window, _ = _window_sizes(spec, part)
+    slots = window_slots(_window_sizes(spec, part)[0])
     if spec.num_atoms == 0:
-        return jnp.full((grid, window), identity, dtype)
-
-    atom_base = part.atom_starts[:-1]                       # [G]
-    idx = atom_base[:, None] + jnp.arange(window, dtype=jnp.int32)[None, :]
-    valid = idx < part.atom_starts[1:, None]                # [G, W]
-    safe_idx = jnp.clip(idx, 0, max(spec.num_atoms - 1, 0))
+        return jnp.full((grid, slots), identity, dtype)
+    pos, valid = _window_slot_view(part.atom_starts, slots)
+    safe_idx = jnp.clip(pos, 0, spec.num_atoms - 1)
     if atom_mask is not None:
         valid = jnp.logical_and(valid, atom_mask[safe_idx])
-    values = atom_fn(safe_idx.reshape(-1)).astype(dtype).reshape(grid, window)
+    values = lane_take(atom_values(atom_fn, spec.num_atoms, dtype), safe_idx)
     return jnp.where(valid, values, jnp.asarray(identity, dtype))
 
 
 def native_chunk_value_windows(spec: WorkSpec, part: Partition,
                                atom_fn: AtomFn, dtype=jnp.float32, *,
                                combiner: str = "sum",
-                               atom_mask: jax.Array | None = None,
-                               interpret: bool = True) -> jax.Array:
+                               atom_mask: jax.Array | None = None) -> jax.Array:
     """Per-chunk masked value windows via the chunk-walking Pallas kernel.
 
     The device-side counterpart of :func:`blocked_value_windows`: the same
     grid/queue discipline as :func:`native_chunk_tile_reduce`, with the
     kernel's ``emit="atoms"`` mode writing the masked window itself instead
     of per-tile bins.  Chunk boundaries equal the pure path's logical block
-    boundaries (``part.atom_starts``), so both paths produce identical
-    windows — the scatter step is shared and the paths stay bit-identical.
+    boundaries (``part.atom_starts``) and both use the shared window layout,
+    so both paths produce identical windows — the scatter step is shared
+    and the paths stay bit-identical.
     """
     identity = _check_combiner(combiner, dtype)
     if jnp.dtype(dtype) != jnp.dtype(jnp.float32):
@@ -392,31 +480,49 @@ def native_chunk_value_windows(spec: WorkSpec, part: Partition,
     if not supports_native_execution(part):
         raise ValueError("partition does not support the native path "
                          "(see supports_native_execution)")
-    window, local_tiles = _window_sizes(spec, part)
+    window, _ = _window_sizes(spec, part)
     if spec.num_atoms == 0:
-        return jnp.full((part.num_blocks, window), identity, dtype)
+        return jnp.full((part.num_blocks, window_slots(window)), identity,
+                        dtype)
+    values = _masked_values(atom_fn, spec.num_atoms, dtype, identity,
+                            atom_mask)
+    return _native_windows(part, values, part.atom_starts, window, combiner)
+
+
+def _native_windows(part: Partition, values: jax.Array, starts: jax.Array,
+                    window: int, combiner: str) -> jax.Array:
+    """``emit="atoms"`` kernel windows of ``values`` over chunk ``starts``,
+    popped in ``part``'s queue order."""
     from repro.kernels.spmv_merge.kernel import chunk_walk_reduce
 
     block_chunks, counts, _ = _chunk_queue_view(part)
-    max_chunks = int(block_chunks.shape[1])
-
-    atoms = jnp.arange(spec.num_atoms, dtype=jnp.int32)
-    values = atom_fn(atoms).astype(dtype)
-    values = jnp.concatenate([values, jnp.full((window,), identity, dtype)])
-    mask = None
-    if atom_mask is not None:
-        mask = jnp.concatenate(
-            [atom_mask.astype(jnp.int32),
-             jnp.zeros((window,), jnp.int32)])
-
-    # no tile-id operand: atoms mode never bins locally
+    starts = starts.astype(jnp.int32)
     return chunk_walk_reduce(
-        values, None, part.atom_starts.astype(jnp.int32),
-        part.tile_starts.astype(jnp.int32),
-        block_chunks.reshape(-1).astype(jnp.int32),
-        counts.astype(jnp.int32), mask,
-        window=window, local_tiles=local_tiles, max_chunks=max_chunks,
-        combiner=combiner, emit="atoms", interpret=interpret)
+        values, None, starts, jnp.zeros_like(starts),
+        block_chunks.reshape(-1).astype(jnp.int32), counts.astype(jnp.int32),
+        window=window, local_tiles=1, max_chunks=int(block_chunks.shape[1]),
+        combiner=combiner, emit="atoms")
+
+
+def _values_by_position(starts: jax.Array, windows: jax.Array,
+                        num: int) -> jax.Array:
+    """Read value windows back in position order: ``[num]`` values.
+
+    Position ``k`` sits in the row of the chunk holding it, at slot ``k -
+    origin[c]`` of the shared layout (:func:`_window_slot_view`): flat slot
+    ``k + c * slots - origin[c]``.  That shift is constant over each chunk,
+    so it is one prefix sum of its steps at the chunk starts.
+    """
+    starts = starts.astype(jnp.int32)
+    slots = int(windows.shape[1])
+    num_chunks = int(starts.shape[0]) - 1
+    shift = (jnp.arange(num_chunks, dtype=jnp.int32) * slots
+             - (starts[:-1] // WINDOW_ALIGN) * WINDOW_ALIGN)
+    steps = jnp.zeros((num,), jnp.int32).at[starts[1:-1]].add(
+        jnp.diff(shift), mode="drop")
+    slot = (jnp.arange(num, dtype=jnp.int32) + shift[0]
+            + jnp.cumsum(steps, dtype=jnp.int32))
+    return lane_take(windows.reshape(-1), slot)
 
 
 def scatter_value_windows(spec: WorkSpec, part: Partition,
@@ -425,21 +531,17 @@ def scatter_value_windows(spec: WorkSpec, part: Partition,
     """Combine value windows by per-atom output ids (``[num_out]`` result).
 
     The second half of a scatter-reduce and the sibling of
-    :func:`fixup_partials`: window slot ``(b, i)`` holds atom
-    ``atom_starts[b] + i``, whose output segment is ``out_ids`` of that atom
-    (e.g. the edge's *destination* vertex in a push advance — the pull form
-    of ``atomicMin`` by destination).  Out-of-range slots and masked atoms
-    already carry the combiner's identity, so they drop out of the segmented
-    reduce; output segments nothing scatters to come back as the identity,
-    exactly like untouched tiles of a tile-reduce.
+    :func:`fixup_partials`: each atom's value is read back from its block's
+    window (:func:`_values_by_position`) and reduced into segment
+    ``out_ids`` of that atom (e.g. the edge's *destination* vertex in a
+    push advance — the pull form of ``atomicMin`` by destination), in
+    ascending atom order; ids equal to ``num_out`` are dropped.  Masked
+    atoms carry the combiner's identity, so output segments nothing
+    scatters to come back as the identity, exactly like untouched tiles of
+    a tile-reduce.
     """
-    window = int(windows.shape[1])
-    idx = part.atom_starts[:-1, None] + jnp.arange(window,
-                                                   dtype=jnp.int32)[None, :]
-    safe_idx = jnp.clip(idx, 0, max(spec.num_atoms - 1, 0))
-    gid = jnp.where(idx < spec.num_atoms, out_ids[safe_idx], num_out)
-    return _segment_reduce(combiner, windows.reshape(-1), gid.reshape(-1),
-                          num_out + 1)[:-1]
+    values = _values_by_position(part.atom_starts, windows, spec.num_atoms)
+    return _segment_reduce(combiner, values, out_ids, num_out + 1)[:-1]
 
 
 # -- gather-compacted active-atom windows (sparse-frontier push mode) -------
@@ -468,7 +570,7 @@ def compact_chunk_starts(num_chunks: int, capacity: int) -> jax.Array:
     gather.  The chunk count mirrors the partition's own so the dynamic
     schedules' queue discipline (``block_chunks``) applies unchanged.
     """
-    per = -(-max(capacity, 1) // max(num_chunks, 1))
+    per = _compact_window(num_chunks, capacity)
     return jnp.minimum(jnp.arange(num_chunks + 1, dtype=jnp.int32) * per,
                        capacity)
 
@@ -478,23 +580,27 @@ def _compact_window(num_chunks: int, capacity: int) -> int:
 
 
 def _compact_slot_view(spec: WorkSpec, idx: jax.Array, num_chunks: int,
-                       window: int):
+                       slots: int) -> Tuple[jax.Array, jax.Array]:
     """Shared slot -> atom addressing of the compacted windows.
 
-    Returns ``(a, valid, safe_a)`` for the ``[num_chunks, window]`` slot
-    grid: the compacted atom id per slot, whether the slot holds a real
-    active atom (in-chunk and in-range), and a clamped id safe to gather
-    with.  The window producers and :func:`scatter_compact_windows` MUST
-    agree on this mapping — that is the whole correctness coupling of the
-    compact mode, so it lives in exactly one place.
+    Returns ``(valid, safe_a)`` for the ``[num_chunks, slots]`` slot grid
+    of the shared window layout over the compacted positions: whether the
+    slot holds a real active atom (in-chunk and in-range), and a clamped
+    atom id safe to gather with.
     """
     capacity = int(idx.shape[0])
-    starts = compact_chunk_starts(num_chunks, capacity)
-    slot = starts[:-1, None] + jnp.arange(window, dtype=jnp.int32)[None, :]
-    a = idx[jnp.clip(slot, 0, capacity - 1)]
-    valid = jnp.logical_and(slot < starts[1:, None], a < spec.num_atoms)
-    safe_a = jnp.clip(a, 0, max(spec.num_atoms - 1, 0))
-    return a, valid, safe_a
+    pos, in_chunk = _window_slot_view(
+        compact_chunk_starts(num_chunks, capacity), slots)
+    a = idx[jnp.clip(pos, 0, capacity - 1)]
+    valid = jnp.logical_and(in_chunk, a < spec.num_atoms)
+    return valid, jnp.clip(a, 0, max(spec.num_atoms - 1, 0))
+
+
+def _compact_slots(part: Partition, idx: jax.Array) -> Tuple[int, int]:
+    """(num_chunks, slots) of the compacted windows over ``part``."""
+    num_chunks = int(part.atom_starts.shape[0]) - 1
+    return num_chunks, window_slots(_compact_window(num_chunks,
+                                                    int(idx.shape[0])))
 
 
 def blocked_compact_value_windows(spec: WorkSpec, part: Partition,
@@ -503,35 +609,33 @@ def blocked_compact_value_windows(spec: WorkSpec, part: Partition,
                                   combiner: str = "sum") -> jax.Array:
     """Per-chunk value windows over a *compacted* active-atom list (pure).
 
-    The sparse-frontier sibling of :func:`blocked_value_windows`: window
-    slot ``(c, i)`` holds the value of compacted atom
-    ``idx[compact_chunk_starts(c) + i]`` — only active atoms occupy slots,
-    so the streamed window volume is the capacity, not the edge count.
-    Padded index slots (``idx`` carries ``num_atoms`` past the true active
-    count) come back as the combiner's identity.
+    The sparse-frontier sibling of :func:`blocked_value_windows`: the
+    windows walk even chunk splits of the compacted positions
+    (:func:`compact_chunk_starts`), and the slot at position ``k`` holds the
+    value of atom ``idx[k]`` — only active atoms occupy slots, so the
+    streamed window volume is the capacity, not the edge count.  Padded
+    index slots (``idx`` carries ``num_atoms`` past the true active count)
+    come back as the combiner's identity.
     """
     identity = _check_combiner(combiner, dtype)
-    num_chunks = int(part.atom_starts.shape[0]) - 1
-    window = _compact_window(num_chunks, int(idx.shape[0]))
-    _, valid, safe_a = _compact_slot_view(spec, idx, num_chunks, window)
-    values = atom_fn(safe_a.reshape(-1)).astype(dtype).reshape(num_chunks,
-                                                               window)
+    num_chunks, slots = _compact_slots(part, idx)
+    valid, safe_a = _compact_slot_view(spec, idx, num_chunks, slots)
+    values = lane_take(atom_values(atom_fn, spec.num_atoms, dtype), safe_a)
     return jnp.where(valid, values, jnp.asarray(identity, dtype))
 
 
 def native_compact_value_windows(spec: WorkSpec, part: Partition,
                                  atom_fn: AtomFn, idx: jax.Array,
                                  dtype=jnp.float32, *,
-                                 combiner: str = "sum",
-                                 interpret: bool = True) -> jax.Array:
-    """Compacted value windows via the chunk-walking kernel's gather mode.
+                                 combiner: str = "sum") -> jax.Array:
+    """Compacted value windows via the chunk-walking kernel.
 
-    Same chunk/queue discipline as :func:`native_chunk_value_windows`, with
-    ``emit="compact"``: the kernel walks even chunk splits of the compacted
-    index list and gathers each slot's value through the indirection —
-    streaming only active atoms.  Chunk boundaries equal the pure path's,
-    so both paths produce identical windows and share one
-    :func:`scatter_compact_windows` call.
+    The gather through the compacted index list runs in XLA before the
+    launch (Mosaic has no in-kernel 1-D gather); the kernel then walks even
+    chunk splits of the gathered values in its ``emit="atoms"`` mode, in
+    the partition's queue order — streaming only active atoms.  Chunk
+    boundaries and layout equal the pure path's, so both paths produce
+    identical windows and share one :func:`scatter_compact_windows` call.
     """
     identity = _check_combiner(combiner, dtype)
     if jnp.dtype(dtype) != jnp.dtype(jnp.float32):
@@ -539,30 +643,15 @@ def native_compact_value_windows(spec: WorkSpec, part: Partition,
     if not supports_native_execution(part):
         raise ValueError("partition does not support the native path "
                          "(see supports_native_execution)")
-    from repro.kernels.spmv_merge.kernel import chunk_walk_reduce
-
-    num_chunks = int(part.atom_starts.shape[0]) - 1
+    num_chunks, _ = _compact_slots(part, idx)
     capacity = int(idx.shape[0])
-    window = _compact_window(num_chunks, capacity)
-    starts = compact_chunk_starts(num_chunks, capacity)
-    block_chunks, counts, _ = _chunk_queue_view(part)
-    max_chunks = int(block_chunks.shape[1])
-
-    atoms = jnp.arange(spec.num_atoms, dtype=jnp.int32)
-    values = atom_fn(atoms).astype(dtype)
-    # identity padding doubles as the gather target of padded index slots
-    values = jnp.concatenate([values, jnp.full((window,), identity, dtype)])
-    idx_padded = jnp.concatenate(
-        [jnp.minimum(idx, spec.num_atoms),      # padded ids -> identity slot
-         jnp.full((window,), spec.num_atoms, jnp.int32)])
-
-    return chunk_walk_reduce(
-        values, None, starts.astype(jnp.int32),
-        jnp.zeros_like(starts),                  # no tile structure
-        block_chunks.reshape(-1).astype(jnp.int32),
-        counts.astype(jnp.int32), None, idx_padded,
-        window=window, local_tiles=1, max_chunks=max_chunks,
-        combiner=combiner, emit="compact", interpret=interpret)
+    active = idx < spec.num_atoms
+    values = lane_take(atom_values(atom_fn, spec.num_atoms, dtype),
+                       jnp.clip(idx, 0, max(spec.num_atoms - 1, 0)))
+    values = jnp.where(active, values, jnp.asarray(identity, dtype))
+    return _native_windows(part, values,
+                           compact_chunk_starts(num_chunks, capacity),
+                           _compact_window(num_chunks, capacity), combiner)
 
 
 def scatter_compact_windows(spec: WorkSpec, windows: jax.Array,
@@ -570,19 +659,21 @@ def scatter_compact_windows(spec: WorkSpec, windows: jax.Array,
                             num_out: int, combiner: str = "sum") -> jax.Array:
     """Combine compacted value windows by per-atom output ids.
 
-    The compact-mode sibling of :func:`scatter_value_windows`: window slot
-    ``(c, i)`` holds compacted atom ``idx[starts[c] + i]``, whose output
-    segment is that atom's ``out_ids`` entry.  Padded/out-of-range slots
-    already carry the combiner's identity and are routed to the dropped
-    overflow segment.  Active atoms keep their ascending order, so for the
-    exact combiners — and exactly-summable values — results are
-    bit-identical to the masked full-window scatter.
+    The compact-mode sibling of :func:`scatter_value_windows`: the value at
+    compacted position ``k`` belongs to atom ``idx[k]``, whose output
+    segment is that atom's ``out_ids`` entry; padded positions are dropped.
+    Active atoms keep their ascending order, so for the exact combiners —
+    and exactly-summable values — results are bit-identical to the masked
+    full-window scatter.
     """
-    num_chunks, window = int(windows.shape[0]), int(windows.shape[1])
-    _, valid, safe_a = _compact_slot_view(spec, idx, num_chunks, window)
-    gid = jnp.where(valid, out_ids[safe_a], num_out)
-    return _segment_reduce(combiner, windows.reshape(-1), gid.reshape(-1),
-                           num_out + 1)[:-1]
+    capacity = int(idx.shape[0])
+    values = _values_by_position(
+        compact_chunk_starts(int(windows.shape[0]), capacity), windows,
+        capacity)
+    gid = jnp.where(idx < spec.num_atoms,
+                    out_ids[jnp.clip(idx, 0, max(spec.num_atoms - 1, 0))],
+                    num_out)
+    return _segment_reduce(combiner, values, gid, num_out + 1)[:-1]
 
 
 def execute_scatter_reduce(spec: WorkSpec, part: Partition, atom_fn: AtomFn,
@@ -591,8 +682,7 @@ def execute_scatter_reduce(spec: WorkSpec, part: Partition, atom_fn: AtomFn,
                            path: ExecutionPath | str = ExecutionPath.AUTO,
                            combiner: str = "sum",
                            atom_mask: jax.Array | None = None,
-                           compact_capacity: int | None = None,
-                           interpret: bool = True) -> jax.Array:
+                           compact_capacity: int | None = None) -> jax.Array:
     """One API over both scatter-reduce executors (the push-advance call).
 
     Balanced per-atom value production over ``spec``/``part`` (any schedule,
@@ -628,8 +718,7 @@ def execute_scatter_reduce(spec: WorkSpec, part: Partition, atom_fn: AtomFn,
         if resolved == ExecutionPath.NATIVE:
             windows = native_chunk_value_windows(spec, part, atom_fn, dtype,
                                                  combiner=combiner,
-                                                 atom_mask=atom_mask,
-                                                 interpret=interpret)
+                                                 atom_mask=atom_mask)
         else:
             windows = blocked_value_windows(spec, part, atom_fn, dtype,
                                             combiner=combiner,
@@ -645,8 +734,7 @@ def execute_scatter_reduce(spec: WorkSpec, part: Partition, atom_fn: AtomFn,
     def compact(_):
         if resolved == ExecutionPath.NATIVE:
             windows = native_compact_value_windows(spec, part, atom_fn, idx,
-                                                   dtype, combiner=combiner,
-                                                   interpret=interpret)
+                                                   dtype, combiner=combiner)
         else:
             windows = blocked_compact_value_windows(spec, part, atom_fn, idx,
                                                     dtype, combiner=combiner)
@@ -660,8 +748,7 @@ def execute_tile_reduce(spec: WorkSpec, part: Partition, atom_fn: AtomFn,
                         dtype=jnp.float32, *,
                         path: ExecutionPath | str = ExecutionPath.AUTO,
                         combiner: str = "sum",
-                        atom_mask: jax.Array | None = None,
-                        interpret: bool = True) -> jax.Array:
+                        atom_mask: jax.Array | None = None) -> jax.Array:
     """One API over both executors — the dispatcher the ops layers call.
 
     Routes any Partition (static, chunked_rr/chunked_lpt, adaptive) to the
@@ -679,8 +766,7 @@ def execute_tile_reduce(spec: WorkSpec, part: Partition, atom_fn: AtomFn,
     if resolved == ExecutionPath.NATIVE:
         return native_chunk_tile_reduce(spec, part, atom_fn, dtype,
                                         combiner=combiner,
-                                        atom_mask=atom_mask,
-                                        interpret=interpret)
+                                        atom_mask=atom_mask)
     return blocked_tile_reduce(spec, part, atom_fn, dtype,
                                combiner=combiner, atom_mask=atom_mask)
 
@@ -703,8 +789,7 @@ def execute_sharded_tile_reduce(spec: WorkSpec, part: Partition,
                                 axis_name: str = "shard",
                                 path: ExecutionPath | str = ExecutionPath.AUTO,
                                 combiner: str = "sum",
-                                atom_mask: jax.Array | None = None,
-                                interpret: bool = True) -> jax.Array:
+                                atom_mask: jax.Array | None = None) -> jax.Array:
     """:func:`execute_tile_reduce` inside a ``shard_map`` body.
 
     The pull-direction shard contract: each shard's local spec owns *all*
@@ -716,8 +801,7 @@ def execute_sharded_tile_reduce(spec: WorkSpec, part: Partition,
     """
     del axis_name  # pull owns all in-edges of its tiles; purely local
     return execute_tile_reduce(spec, part, atom_fn, dtype, path=path,
-                               combiner=combiner, atom_mask=atom_mask,
-                               interpret=interpret)
+                               combiner=combiner, atom_mask=atom_mask)
 
 
 def execute_sharded_scatter_reduce(spec: WorkSpec, part: Partition,
@@ -728,8 +812,7 @@ def execute_sharded_scatter_reduce(spec: WorkSpec, part: Partition,
                                    ExecutionPath.AUTO,
                                    combiner: str = "sum",
                                    atom_mask: jax.Array | None = None,
-                                   compact_capacity: int | None = None,
-                                   interpret: bool = True) -> jax.Array:
+                                   compact_capacity: int | None = None) -> jax.Array:
     """:func:`execute_scatter_reduce` inside a ``shard_map`` body.
 
     The push-direction shard contract: each shard streams only its own
@@ -745,6 +828,5 @@ def execute_sharded_scatter_reduce(spec: WorkSpec, part: Partition,
     partial = execute_scatter_reduce(spec, part, atom_fn, out_ids, num_out,
                                      dtype, path=path, combiner=combiner,
                                      atom_mask=atom_mask,
-                                     compact_capacity=compact_capacity,
-                                     interpret=interpret)
+                                     compact_capacity=compact_capacity)
     return COMBINER_COLLECTIVE[combiner](partial, axis_name)

@@ -93,13 +93,16 @@ class WorkSpec:
     def atom_tile_ids(self) -> jax.Array:
         """Map atom index -> owning tile id, shape [num_atoms].
 
-        ``tile_of(a) = max { t : tile_offsets[t] <= a }``.  Uses a single
-        vectorized ``searchsorted`` — the TPU replacement for the per-thread
-        binary search the paper performs inside ``get_tile(atom_id)``.
+        ``tile_of(a) = max { t : tile_offsets[t] <= a }`` — the paper's
+        per-thread ``get_tile(atom_id)`` binary search, done for every atom
+        at once as a count: one mark per tile start, then a prefix sum.
+        That is O(atoms + tiles) work; a vectorized binary search would
+        gather ``num_atoms`` offsets once per halving step.
         """
-        atoms = jnp.arange(self.num_atoms, dtype=jnp.int32)
-        return (jnp.searchsorted(self.tile_offsets, atoms, side="right")
-                .astype(jnp.int32) - 1)
+        starts = self.tile_offsets[1:-1].astype(jnp.int32)
+        marks = jnp.zeros((self.num_atoms,), jnp.int32).at[starts].add(
+            1, mode="drop")
+        return jnp.cumsum(marks, dtype=jnp.int32)
 
     def total_work(self) -> int:
         """Merge-path work measure: one unit per atom + one per tile."""

@@ -3,6 +3,7 @@
 Each kernel ships as a subpackage: ``kernel.py`` (pl.pallas_call + BlockSpec
 VMEM tiling), ``ops.py`` (jitted public wrapper doing the load-balancing
 setup), ``ref.py`` (pure-jnp oracle used by the allclose test sweeps).
-Kernels are validated with ``interpret=True`` on CPU; pass
-``interpret=False`` on real TPU.
+Every kernel launches through :func:`repro.core.execute.pallas_call`, which
+runs it in the Pallas interpreter where the program is lowered for the CPU
+and compiles it natively where it is lowered for a TPU.
 """

@@ -26,7 +26,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
+from repro.core.execute import pallas_call
 
 NEG_INF = -1e30  # plain float: jnp scalars would be captured as consts
 
@@ -75,9 +75,9 @@ def _flash_swa_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
                              ).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("window", "qc", "interpret"))
+@functools.partial(jax.jit, static_argnames=("window", "qc"))
 def flash_swa(q: jax.Array, k: jax.Array, v: jax.Array, *, window: int,
-              qc: int = 256, interpret: bool = True) -> jax.Array:
+              qc: int = 256) -> jax.Array:
     """Causal sliding-window attention.  q/k/v: [B, S, H, hd] (same head
     count — see ops.flash_swa_gqa for GQA); positions 0..S-1; ``window``
     and S must be multiples of ``qc``."""
@@ -93,7 +93,7 @@ def flash_swa(q: jax.Array, k: jax.Array, v: jax.Array, *, window: int,
     def kv_index(bi, hi, i, j):
         return (bi, jnp.maximum(i - wb + j, 0), hi, 0)
 
-    return pl.pallas_call(
+    return pallas_call(
         functools.partial(_flash_swa_kernel, qc=qc, window=window, wb=wb,
                           scale=scale),
         grid=(b, h, nq, wb + 1),
@@ -109,8 +109,7 @@ def flash_swa(q: jax.Array, k: jax.Array, v: jax.Array, *, window: int,
             pltpu.VMEM((qc, 1), jnp.float32),    # running normalizer
             pltpu.VMEM((qc, hd), jnp.float32),   # unnormalized accumulator
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
-        interpret=interpret,
     )(q, k, v)

@@ -29,7 +29,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
+from repro.core.execute import pallas_call
 
 
 def _segmm_kernel(block_expert_ref, lhs_ref, rhs_ref, out_ref):
@@ -46,11 +46,10 @@ def _segmm_kernel(block_expert_ref, lhs_ref, rhs_ref, out_ref):
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("bm", "bn", "bk", "interpret"))
+                   static_argnames=("bm", "bn", "bk"))
 def segmented_matmul(lhs_padded: jax.Array, rhs: jax.Array,
                      block_expert: jax.Array, *, bm: int = 128,
-                     bn: int = 128, bk: int = 512,
-                     interpret: bool = True) -> jax.Array:
+                     bn: int = 128, bk: int = 512) -> jax.Array:
     """``out[i*bm:(i+1)*bm] = lhs[i*bm:(i+1)*bm] @ rhs[block_expert[i]]``.
 
     ``lhs_padded``: ``[M_pad, K]`` tokens sorted by expert, group-padded so
@@ -65,7 +64,7 @@ def segmented_matmul(lhs_padded: jax.Array, rhs: jax.Array,
     assert k_dim % bk == 0 and n_dim % bn == 0
     grid = (m_pad // bm, n_dim // bn, k_dim // bk)
 
-    return pl.pallas_call(
+    return pallas_call(
         _segmm_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
@@ -77,9 +76,8 @@ def segmented_matmul(lhs_padded: jax.Array, rhs: jax.Array,
             out_specs=pl.BlockSpec((bm, bn), lambda i, j, k, be: (i, j)),
         ),
         out_shape=jax.ShapeDtypeStruct((m_pad, n_dim), jnp.float32),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
     )(block_expert, lhs_padded, rhs)
 
 
@@ -123,15 +121,13 @@ def _segmm_chunk_kernel(block_expert_ref, chunks_ref, counts_ref,
     jax.lax.fori_loop(0, max_chunks, pop, 0)
 
 
-@functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "max_chunks",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "max_chunks"))
 def segmented_matmul_chunked(lhs_padded: jax.Array, rhs: jax.Array,
                              block_expert: jax.Array,
                              block_chunks_flat: jax.Array,
                              chunk_counts: jax.Array, *, bm: int = 128,
                              bn: int = 128, bk: int = 512,
-                             max_chunks: int = 1,
-                             interpret: bool = True) -> jax.Array:
+                             max_chunks: int = 1) -> jax.Array:
     """Chunk-walking segmented matmul over ``P`` physical blocks.
 
     Same contract as :func:`segmented_matmul` plus the queue:
@@ -153,7 +149,7 @@ def segmented_matmul_chunked(lhs_padded: jax.Array, rhs: jax.Array,
     # every queue finishes its k-accumulation before the next output wave.
     grid = (n_dim // bn, num_physical, k_dim // bk)
 
-    return pl.pallas_call(
+    return pallas_call(
         functools.partial(_segmm_chunk_kernel, bm=bm, max_chunks=max_chunks),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
@@ -165,7 +161,6 @@ def segmented_matmul_chunked(lhs_padded: jax.Array, rhs: jax.Array,
             out_specs=pl.BlockSpec((m_pad, bn), lambda j, p, k, *_: (0, j)),
         ),
         out_shape=jax.ShapeDtypeStruct((m_pad, n_dim), jnp.float32),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
-        interpret=interpret,
     )(block_expert, block_chunks_flat, chunk_counts, lhs_padded, rhs)

@@ -99,8 +99,7 @@ def level_grouped_matmul(tokens: jax.Array, op_of_token: jax.Array,
                          rhs: jax.Array, *, num_ops: int, plan=None,
                          schedule: str | None = None,
                          path: str | None = None, bm: int = 8,
-                         bn: int = 128, bk: int = 512,
-                         interpret: bool = True) -> jax.Array:
+                         bn: int = 128, bk: int = 512) -> jax.Array:
     """Per-level dense evaluation entry for the wavefront scheduler.
 
     A DAG level is the MoE routing problem with ops for experts: atoms =
@@ -124,15 +123,14 @@ def level_grouped_matmul(tokens: jax.Array, op_of_token: jax.Array,
     return _grouped_matmul(tokens, op_of_token, rhs, num_experts=num_ops,
                            bm=bm, bn=bn, bk=bk,
                            schedule=schedule or "group_mapped",
-                           path=path or "pure", interpret=interpret)
+                           path=path or "pure")
 
 
 @functools.partial(jax.jit, static_argnames=("num_experts", "bm", "bn", "bk",
-                                             "schedule", "path", "interpret"))
+                                             "schedule", "path"))
 def _grouped_matmul(tokens: jax.Array, expert_of_token: jax.Array,
                     rhs: jax.Array, *, num_experts: int, bm: int,
-                    bn: int, bk: int, schedule: str, path: str,
-                    interpret: bool) -> jax.Array:
+                    bn: int, bk: int, schedule: str, path: str) -> jax.Array:
     t_dim, k_dim = tokens.shape
     e_dim = num_experts
     m_pad = _round_up(t_dim + e_dim * (bm - 1), bm)
@@ -185,7 +183,7 @@ def _grouped_matmul(tokens: jax.Array, expert_of_token: jax.Array,
             jnp.asarray(rank.reshape(-1), jnp.int32), nblk - 1)]
         out_padded = _kernel.segmented_matmul_chunked(
             lhs_padded, rhs, block_expert, chunks, counts,
-            bm=bm, bn=bn, bk=bk, max_chunks=cmax, interpret=interpret)
+            bm=bm, bn=bn, bk=bk, max_chunks=cmax)
     else:
         # --- pure/fallback: realize the queue as a host-side block
         # permutation feeding the plain kernel (one M-block per grid step).
@@ -199,8 +197,7 @@ def _grouped_matmul(tokens: jax.Array, expert_of_token: jax.Array,
             m_pad, k_dim)
         be_exec = block_expert[perm]
         out_exec = _kernel.segmented_matmul(lhs_exec, rhs, be_exec,
-                                            bm=bm, bn=bn, bk=bk,
-                                            interpret=interpret)
+                                            bm=bm, bn=bn, bk=bk)
         # un-permute blocks, then unsort (gather each token's padded row)
         inv = jnp.zeros((nblk,), jnp.int32).at[perm].set(
             jnp.arange(nblk, dtype=jnp.int32))
@@ -214,8 +211,7 @@ def grouped_matmul(tokens: jax.Array, expert_of_token: jax.Array,
                    bn: int = 128, bk: int = 512,
                    schedule: str = "group_mapped",
                    execution_path: ExecutionPath | str = ExecutionPath.AUTO,
-                   measure=None,
-                   interpret: bool = True) -> jax.Array:
+                   measure=None) -> jax.Array:
     """``out[t] = tokens[t] @ rhs[expert_of_token[t]]`` for ragged groups.
 
     ``tokens``: ``[T, K]``; ``expert_of_token``: int32 ``[T]`` in
@@ -238,8 +234,7 @@ def grouped_matmul(tokens: jax.Array, expert_of_token: jax.Array,
                     policy, p = plan_policy(plan)
                     f = functools.partial(
                         _grouped_matmul, num_experts=num_experts, bm=bm,
-                        bn=bn, bk=bk, schedule=policy, path=p,
-                        interpret=interpret)
+                        bn=bn, bk=bk, schedule=policy, path=p)
                     return time_fn(f, tokens, expert_of_token, rhs,
                                    warmup=1, iters=3)
         schedule = resolve_schedule(expert_of_token, num_experts,
@@ -251,5 +246,4 @@ def grouped_matmul(tokens: jax.Array, expert_of_token: jax.Array,
     path = resolve_execution_path(execution_path, native_supported=True)
     return _grouped_matmul(tokens, expert_of_token, rhs,
                            num_experts=num_experts, bm=bm, bn=bn, bk=bk,
-                           schedule=schedule, path=str(path),
-                           interpret=interpret)
+                           schedule=schedule, path=str(path))

@@ -18,16 +18,15 @@ explicit instead of searched:
     work (the merge-path guarantee: a block touches at most
     ``block_items + 1`` rows, no matter how skewed the matrix).
 3.  Inside the block, the per-row reduction is a one-hot contraction
-    ``dot(values[W], onehot[W, R_LOC])`` on the **MXU** — the TPU analogue of
-    the warp-cooperative segmented reduction.
+    ``values[1, W] . onehot[R_LOC, W]^T`` on the **MXU** — the TPU analogue
+    of the warp-cooperative segmented reduction.
 4.  Rows crossing block boundaries are resolved by a scatter-add **fixup**
     over the per-block partials (Merrill's "segmented fixup" pass; TPU grid
     blocks must not order-depend, so the fixup is a separate tiny reduction).
 
-VMEM per block: ``block_items``(f32+i32) + ``block_items x R_LOC`` one-hot
-(f32, transient) + ``R_LOC`` partials — ~1.7 MB at the default
-``block_items=512`` (R_LOC=640), comfortably inside the ~16 MB v5e VMEM
-budget, and MXU-aligned (512 and 640 are multiples of 128).
+Every block is a ``(1, 1, n)`` slice of a ``(G, 1, n)`` array: its last two
+dimensions equal the array's, which is what Mosaic's tiling rule asks of a
+block whose second-minor size is not a multiple of 8.
 """
 from __future__ import annotations
 
@@ -38,6 +37,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.execute import WINDOW_ALIGN, pallas_call, window_slots
+
+_SUBLANES, _LANES = 8, 128
+assert WINDOW_ALIGN == _SUBLANES * _LANES   # one f32 VMEM tile per DMA
+
 
 def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m
@@ -47,23 +51,21 @@ def _spmv_block_kernel(row_base_ref, vals_ref, rows_ref, out_ref, *,
                        r_loc: int):
     """One merge-path block: masked one-hot MXU contraction."""
     b = pl.program_id(0)
-    base = row_base_ref[b]
-    local = rows_ref[...].astype(jnp.int32) - base            # [W]
-    vals = vals_ref[...].astype(jnp.float32)                  # [W]
+    local = rows_ref[0] - row_base_ref[b]                          # [1, W]
     # Rows outside [0, r_loc) (markers/padding carry value 0 anyway) simply
-    # match no one-hot column — no explicit mask needed.
-    onehot = (local[:, None]
-              == jax.lax.broadcasted_iota(jnp.int32, (1, r_loc), 1))
-    out_ref[0, :] = jnp.dot(vals, onehot.astype(jnp.float32),
-                            preferred_element_type=jnp.float32)
+    # match no one-hot row — no explicit mask needed.
+    onehot = (jax.lax.broadcasted_iota(jnp.int32, (r_loc, 1), 0)
+              == local).astype(jnp.float32)                        # [R, W]
+    out_ref[0] = jax.lax.dot_general(
+        vals_ref[0], onehot, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)                        # [1, R]
 
 
-@functools.partial(jax.jit, static_argnames=("num_rows", "block_items",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("num_rows", "block_items"))
 def spmv_merge_stream(stream_vals: jax.Array, stream_rows: jax.Array,
                       row_base: jax.Array, *, num_rows: int,
-                      block_items: int = 512,
-                      interpret: bool = True) -> jax.Array:
+                      block_items: int = 512) -> jax.Array:
     """Run the blocked kernel over a pre-built merge stream.
 
     ``stream_vals`` f32 ``[G * block_items]`` (zero at markers/padding),
@@ -75,21 +77,19 @@ def spmv_merge_stream(stream_vals: jax.Array, stream_rows: jax.Array,
     assert total % block_items == 0
     grid = total // block_items
     r_loc = _round_up(block_items + 1, 128)
+    block = pl.BlockSpec((1, 1, block_items), lambda b, rb: (b, 0, 0))
 
-    partials = pl.pallas_call(
+    partials = pallas_call(
         functools.partial(_spmv_block_kernel, r_loc=r_loc),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(grid,),
-            in_specs=[
-                pl.BlockSpec((block_items,), lambda b, rb: (b,)),
-                pl.BlockSpec((block_items,), lambda b, rb: (b,)),
-            ],
-            out_specs=pl.BlockSpec((1, r_loc), lambda b, rb: (b, 0)),
+            in_specs=[block, block],
+            out_specs=pl.BlockSpec((1, 1, r_loc), lambda b, rb: (b, 0, 0)),
         ),
-        out_shape=jax.ShapeDtypeStruct((grid, r_loc), jnp.float32),
-        interpret=interpret,
-    )(row_base, stream_vals, stream_rows)
+        out_shape=jax.ShapeDtypeStruct((grid, 1, r_loc), jnp.float32),
+    )(row_base, stream_vals.astype(jnp.float32).reshape(grid, 1, -1),
+      stream_rows.astype(jnp.int32).reshape(grid, 1, -1))
 
     # Fixup: combine cross-block partial rows (scatter-add over partials).
     gids = row_base[:, None] + jnp.arange(r_loc, dtype=jnp.int32)[None, :]
@@ -104,195 +104,208 @@ def spmv_merge_stream(stream_vals: jax.Array, stream_rows: jax.Array,
 # ---------------------------------------------------------------------------
 
 #: Identity element per combiner, mirrored from
-#: ``repro.core.execute.COMBINER_IDENTITY`` (kept literal here so the
-#: kernel module stays import-light).
+#: ``repro.core.execute.COMBINER_IDENTITY``.
 _IDENTITY = {"sum": 0.0, "min": float("inf"), "max": float("-inf")}
+_COMBINE = {"sum": jnp.add, "min": jnp.minimum, "max": jnp.maximum}
+_REDUCE = {"sum": jnp.sum, "min": jnp.min, "max": jnp.max}
+
+
+def _as_tiles(x: jax.Array, fill) -> jax.Array:
+    """``[..., A]`` -> ``[..., ceil(A / WINDOW_ALIGN) * 8, 128]``, padded with
+    ``fill``."""
+    num = int(x.shape[-1])
+    pad = _round_up(max(num, 1), WINDOW_ALIGN) - num
+    x = jnp.concatenate(
+        [x, jnp.full(x.shape[:-1] + (pad,), fill, x.dtype)], axis=-1)
+    return x.reshape(x.shape[:-1] + (-1, _LANES))
 
 
 def _chunk_walk_kernel(atom_starts_ref, tile_starts_ref, chunks_ref,
-                       counts_ref, *refs,
-                       window: int, local_tiles: int, max_chunks: int,
-                       combiner: str, has_mask: bool, emit: str):
-    """One physical block drains its chunk queue inside the kernel.
+                       counts_ref, *refs, max_chunks: int, combiner: str,
+                       emit: str, bin_rows: int):
+    """Grid step ``(b, p, i)``: lane ``b``'s physical block ``p`` pops its
+    ``i``-th chunk.
 
     The queue discipline of :mod:`repro.core.dynamic` is delivered as the
     scalar-prefetched ``chunks_ref`` row (the inverted, padded view of
-    ``Partition.block_map``).  Each pop processes a static ``window`` of
-    atoms starting at the chunk's ``atom_starts`` boundary (masked past its
-    end) and, for ``emit="tiles"``, reduces into ``local_tiles`` local bins:
-    a one-hot MXU contraction for ``sum`` (same as the merge-path kernel), a
-    masked elementwise reduce for ``min``/``max`` (the graph advance's
-    scatter-min / scatter-or).  ``window``/``local_tiles`` come from the
-    partition's ``atom_span``/``tile_span`` hints — sizing the tile window
-    from the atom count alone would undercount chunks spanning empty tiles
-    (the PR-1 ``blocked_tile_reduce`` hazard), so the hints are mandatory
-    here.
+    ``Partition.block_map``); the output block of step ``(b, p, i)`` is the
+    popped chunk's own row of lane ``b``, so steps past the queue's length
+    (which map to a spare row) write nothing anyone reads.
 
-    ``emit="atoms"`` skips the local binning and writes the masked value
-    window itself — the push-direction graph advance, whose outputs are
-    combined by edge *destination* (an id unrelated to the walked tile
-    structure) in a host-side segmented scatter.  The chunk walk, the
-    frontier-mask operand, and the window discipline are identical; only
-    the output row semantics change (per-atom values instead of per-tile
-    partials).
+    The operands stay in HBM as ``[rows, 128]`` views (values with a
+    leading lane axis).  The chunk's atoms ``[atom_starts[c],
+    atom_starts[c+1])`` are walked one ``(8, 128)`` tile at a time, DMA'd
+    from the tile that holds the chunk's first atom; each slot is masked by
+    its *global* atom index, so no read starts mid-tile.
 
-    ``emit="compact"`` is the gather-compacted sibling of ``"atoms"``: the
-    chunk boundaries cover a *compacted active-atom index list* (an extra
-    int32 operand), not the full atom set, and each window slot gathers its
-    value through that indirection — ``vals[idx[slot]]`` — so the kernel
-    streams only the frontier's out-edges instead of masking full windows.
-    No mask operand is needed (the compaction already applied it); padded
-    index slots point at the values array's identity padding.  Note for a
-    real-TPU port: the per-slot gather is the one new Mosaic demand of this
-    mode (see docs/graph.md, "Compacted frontier windows").
-
-    With ``has_mask`` an extra int32 operand rides next to the values: the
-    per-atom frontier mask of a graph advance.  Masked atoms behave exactly
-    like atoms past the chunk's end (identity value, OOB local bin).  In
-    ``emit="atoms"``/``"compact"`` modes no tile-id operand is streamed at
-    all — the binning it feeds never happens.
+    ``emit="tiles"`` reduces the walked values into local tile bins
+    ``tids - tile_starts[c]`` (the output row, ``bin_rows x 128`` bins):
+    each 128-atom column of a tile is compared against the bins of the tile
+    rows its atoms reach, and the masked values are reduced over atoms with
+    the combiner.  ``emit="atoms"`` writes the masked tiles themselves: the
+    output row holds the values of the atoms from the chunk's window origin
+    on, identity outside the chunk.
     """
-    tids_ref = mask_ref = idx_ref = None
-    if emit == "compact":
-        vals_ref, idx_ref, out_ref = refs
-    elif emit == "atoms":
-        if has_mask:
-            vals_ref, mask_ref, out_ref = refs
-        else:
-            vals_ref, out_ref = refs
-    elif has_mask:
-        vals_ref, tids_ref, mask_ref, out_ref = refs
+    if emit == "tiles":
+        vals_hbm, tids_hbm, out_ref, vals_buf, tids_buf, bounds, sem = refs
     else:
-        vals_ref, tids_ref, out_ref = refs
+        vals_hbm, out_ref, vals_buf, sem = refs
     identity = _IDENTITY[combiner]
-    p = pl.program_id(0)
-    count = counts_ref[p]
+    b, p, i = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    out_ref[...] = jnp.full(out_ref.shape, identity, jnp.float32)
 
-    def pop(i, carry):
-        @pl.when(i < count)
-        def _process():
-            c = chunks_ref[p * max_chunks + i]
-            base = atom_starts_ref[c]
-            end = atom_starts_ref[c + 1]
-            tbase = tile_starts_ref[c]
-            idx = base + jax.lax.broadcasted_iota(jnp.int32, (1, window), 1)
-            ok = (idx < end)[0]                                   # [W]
-            if mask_ref is not None:
-                ok = jnp.logical_and(
-                    ok, mask_ref[pl.ds(base, window)] != 0)
-            if emit == "compact":
-                # gather through the compacted index list: padded slots
-                # point past the atom set, into the values array's
-                # identity padding (and are masked besides)
-                gathered = idx_ref[pl.ds(base, window)].astype(jnp.int32)
-                vals = vals_ref[...].astype(jnp.float32)[gathered]
-            else:
-                vals = vals_ref[pl.ds(base, window)].astype(jnp.float32)
-            vals = jnp.where(ok, vals, identity)                  # [W]
-            if emit in ("atoms", "compact"):
-                out_ref[pl.ds(c, 1), :] = vals[None, :]
-                return
-            local = tids_ref[pl.ds(base, window)].astype(jnp.int32) - tbase
-            local = jnp.where(ok, local, local_tiles)             # [W]
-            onehot = (local[:, None] == jax.lax.broadcasted_iota(
-                jnp.int32, (1, local_tiles), 1))                  # [W, L]
-            if combiner == "sum":
-                out_ref[pl.ds(c, 1), :] = jnp.dot(
-                    vals[None, :], onehot.astype(jnp.float32),
-                    preferred_element_type=jnp.float32)
-            else:
-                contrib = jnp.where(onehot, vals[:, None],
-                                    jnp.float32(identity))        # [W, L]
-                red = (contrib.min(axis=0) if combiner == "min"
-                       else contrib.max(axis=0))
-                out_ref[pl.ds(c, 1), :] = red[None, :]
-        return carry
+    @pl.when(i < counts_ref[p])
+    def _walk():
+        c = chunks_ref[p * max_chunks + i]
+        base, end = atom_starts_ref[c], atom_starts_ref[c + 1]
+        first = base // WINDOW_ALIGN
+        slot = (jax.lax.broadcasted_iota(jnp.int32, (_SUBLANES, _LANES), 0)
+                * _LANES
+                + jax.lax.broadcasted_iota(jnp.int32, (_SUBLANES, _LANES), 1))
 
-    jax.lax.fori_loop(0, max_chunks, pop, 0)
+        def fetch(src, dst, t):
+            row = pl.multiple_of((first + t) * _SUBLANES, _SUBLANES)
+            copy = pltpu.make_async_copy(src.at[pl.ds(row, _SUBLANES)], dst,
+                                         sem)
+            copy.start()
+            copy.wait()
+
+        def tile(t, carry):
+            fetch(vals_hbm.at[b], vals_buf, t)
+            atom = (first + t) * WINDOW_ALIGN + slot
+            ok = jnp.logical_and(atom >= base, atom < end)
+            vals = jnp.where(ok, vals_buf[...], identity)
+            if emit == "atoms":
+                out_ref[pl.ds(pl.multiple_of(t * _SUBLANES, _SUBLANES),
+                              _SUBLANES), :] = vals
+                return carry
+            fetch(tids_hbm, tids_buf, t)
+            local = jnp.where(ok, tids_buf[...] - tile_starts_ref[c], -1)
+            # bin rows this tile reaches (atoms are sorted by tile)
+            bounds[0] = jnp.min(jnp.where(ok, local, bin_rows * _LANES))
+            bounds[1] = jnp.max(local)
+            vals_t, local_t = vals.T, local.T                       # [128, 8]
+            lane = jax.lax.broadcasted_iota(jnp.int32, (1, _LANES), 1)
+
+            def bin_row(r, carry):
+                bins = r * _LANES + lane                             # [1, 128]
+                acc = out_ref[pl.ds(r, 1), :]
+                for k in range(_SUBLANES):
+                    hit = local_t[:, k:k + 1] == bins            # [128, 128]
+                    acc = _COMBINE[combiner](acc, _REDUCE[combiner](
+                        jnp.where(hit, vals_t[:, k:k + 1], identity),
+                        axis=0, keepdims=True))
+                out_ref[pl.ds(r, 1), :] = acc
+                return carry
+
+            jax.lax.fori_loop(bounds[0] // _LANES, bounds[1] // _LANES + 1,
+                              bin_row, 0)
+            return carry
+
+        jax.lax.fori_loop(0, pl.cdiv(end, WINDOW_ALIGN) - first, tile, 0)
+
+
+def _launch(vals, tids, atom_starts, tile_starts, block_chunks_flat,
+            chunk_counts, *, window: int, local_tiles: int, max_chunks: int,
+            combiner: str, emit: str) -> jax.Array:
+    """The kernel over lanes ``vals [B, A]``; returns ``[B, C, cols]``."""
+    lanes = int(vals.shape[0])
+    num_chunks = int(atom_starts.shape[0]) - 1
+    num_physical = int(chunk_counts.shape[0])
+    operands = [_as_tiles(vals.astype(jnp.float32), 0.0)]
+    scratch = [pltpu.VMEM((_SUBLANES, _LANES), jnp.float32)]
+    if emit == "tiles":
+        rows = -(-max(local_tiles, 1) // _LANES)
+        operands.append(_as_tiles(tids.astype(jnp.int32), 0))
+        scratch += [pltpu.VMEM((_SUBLANES, _LANES), jnp.int32),
+                    pltpu.SMEM((2,), jnp.int32)]
+    else:
+        rows = window_slots(window) // _LANES
+
+    def out_row(b, p, i, starts, tstarts, chunks, counts):
+        # steps past the queue's end write the spare row ``num_chunks``
+        return (b, jnp.where(i < counts[p], chunks[p * max_chunks + i],
+                             num_chunks), 0, 0)
+
+    out = pallas_call(
+        functools.partial(_chunk_walk_kernel, max_chunks=max_chunks,
+                          combiner=combiner, emit=emit, bin_rows=rows),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(lanes, num_physical, max_chunks),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * len(operands),
+            out_specs=pl.BlockSpec((None, None, rows, _LANES), out_row),
+            scratch_shapes=scratch + [pltpu.SemaphoreType.DMA(())],
+        ),
+        out_shape=jax.ShapeDtypeStruct((lanes, num_chunks + 1, rows, _LANES),
+                                       jnp.float32),
+    )(atom_starts, tile_starts, block_chunks_flat, chunk_counts, *operands)
+    out = out[:, :num_chunks].reshape(lanes, num_chunks, rows * _LANES)
+    return out[..., :local_tiles] if emit == "tiles" else out
 
 
 @functools.partial(jax.jit, static_argnames=("window", "local_tiles",
                                              "max_chunks", "combiner",
-                                             "interpret", "emit"))
-def chunk_walk_reduce(vals_padded: jax.Array,
-                      tids_padded: jax.Array | None,
+                                             "emit"))
+def chunk_walk_reduce(vals: jax.Array, tids: jax.Array | None,
                       atom_starts: jax.Array, tile_starts: jax.Array,
                       block_chunks_flat: jax.Array, chunk_counts: jax.Array,
-                      mask_padded: jax.Array | None = None,
-                      idx_padded: jax.Array | None = None,
                       *, window: int, local_tiles: int, max_chunks: int,
-                      combiner: str = "sum", emit: str = "tiles",
-                      interpret: bool = True) -> jax.Array:
+                      combiner: str = "sum", emit: str = "tiles"
+                      ) -> jax.Array:
     """Per-chunk partial tile reductions via the chunk-walking Pallas kernel.
 
-    ``vals_padded`` f32 ``[A + window]`` (per-atom values, identity-padded),
-    ``tids_padded`` int32 ``[A + window]`` (owning tile per atom, padding
-    maps past ``local_tiles``), ``atom_starts``/``tile_starts`` int32
-    ``[C + 1]`` chunk boundaries, ``block_chunks_flat`` int32
-    ``[P * max_chunks]`` (row ``p`` = physical block ``p``'s queue), and
-    ``chunk_counts`` int32 ``[P]``.  ``mask_padded`` (optional int32
-    ``[A + window]``, zero-padded) is the frontier-mask operand: atoms with
-    mask 0 contribute the combiner's identity.  Grid = ``P`` physical
-    blocks; every chunk row of the ``[C, local_tiles]`` result is written by
-    exactly the block that owns it.  The caller resolves cross-chunk partial
-    tiles with the shared fixup (see
-    :func:`repro.core.execute.fixup_partials`).
+    ``vals`` f32 ``[A]`` (per-atom values, the frontier mask already applied
+    as the combiner's identity), ``tids`` int32 ``[A]`` (owning tile per
+    atom), ``atom_starts``/``tile_starts`` int32 ``[C + 1]`` chunk
+    boundaries, ``block_chunks_flat`` int32 ``[P * max_chunks]`` (row ``p``
+    = physical block ``p``'s queue), and ``chunk_counts`` int32 ``[P]``.
+    Grid = ``(lanes, P, max_chunks)``; every chunk row of the ``[C,
+    local_tiles]`` result is written by exactly the step that pops it.  The
+    caller resolves cross-chunk partial tiles with the shared fixup (see
+    :func:`repro.core.execute.fixup_partials`).  ``window`` bounds the atoms
+    of any chunk.
 
-    ``emit="atoms"`` returns ``[C, window]`` masked value windows instead of
-    per-tile partials (the push-direction advance; the caller combines by
+    ``emit="atoms"`` returns ``[C, window_slots(window)]`` masked value
+    windows instead of per-tile partials, each row starting at its chunk's
+    window origin (the push-direction advance; the caller combines by
     per-atom destination ids — see
-    :func:`repro.core.execute.scatter_value_windows`).  ``tids_padded``
-    is unused (pass ``None``): the kernel streams no tile-id operand.
+    :func:`repro.core.execute.scatter_value_windows`).  ``tids`` is unused
+    (pass ``None``).
 
-    ``emit="compact"`` additionally takes ``idx_padded`` (int32
-    ``[capacity + window]``, the compacted active-atom ids, padded past
-    ``capacity`` with indices into ``vals_padded``'s identity padding);
-    ``atom_starts`` then holds chunk boundaries over ``[0, capacity]`` and
-    each window slot gathers ``vals_padded[idx_padded[slot]]`` — the
-    frontier-compacted window mode (no ``mask_padded``: compaction already
-    applied the mask).  Output is ``[C, window]`` windows of *compacted*
-    values; the caller combines them with
-    :func:`repro.core.execute.scatter_compact_windows`.
+    Under ``jax.vmap`` over ``vals`` (serving lanes, multi-source BFS) the
+    lanes become the kernel's leading grid axis, sharing one copy of the
+    chunk structure and ``tids``: Mosaic cannot batch an HBM operand itself.
+    Lanes that carry their own chunk structure run one launch each.
     """
     if combiner not in _IDENTITY:
         raise ValueError(f"unknown combiner: {combiner!r}")
-    if emit not in ("tiles", "atoms", "compact"):
+    if emit not in ("tiles", "atoms"):
         raise ValueError(f"unknown emit mode: {emit!r}")
-    if emit == "compact" and (idx_padded is None or mask_padded is not None):
-        raise ValueError("emit='compact' needs idx_padded and no "
-                         "mask_padded (compaction already applied the mask)")
-    num_chunks = int(atom_starts.shape[0]) - 1
-    num_physical = int(chunk_counts.shape[0])
-    a_pad = int(vals_padded.shape[0])
-    has_mask = mask_padded is not None
-    out_cols = local_tiles if emit == "tiles" else window
-
-    in_specs = [pl.BlockSpec((a_pad,), lambda p, *_: (0,))]
-    operands = [vals_padded]
+    launch = functools.partial(_launch, window=window,
+                               local_tiles=local_tiles,
+                               max_chunks=max_chunks, combiner=combiner,
+                               emit=emit)
+    shared = (atom_starts, tile_starts, block_chunks_flat, chunk_counts)
     if emit == "tiles":
-        in_specs.append(pl.BlockSpec((a_pad,), lambda p, *_: (0,)))
-        operands.append(tids_padded)
-    if emit == "compact":
-        i_pad = int(idx_padded.shape[0])
-        in_specs.append(pl.BlockSpec((i_pad,), lambda p, *_: (0,)))
-        operands.append(idx_padded)
-    if has_mask:
-        in_specs.append(pl.BlockSpec((a_pad,), lambda p, *_: (0,)))
-        operands.append(mask_padded)
+        shared = (tids,) + shared
 
-    return pl.pallas_call(
-        functools.partial(_chunk_walk_kernel, window=window,
-                          local_tiles=local_tiles, max_chunks=max_chunks,
-                          combiner=combiner, has_mask=has_mask, emit=emit),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
-            grid=(num_physical,),
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec((num_chunks, out_cols),
-                                   lambda p, *_: (0, 0)),
-        ),
-        out_shape=jax.ShapeDtypeStruct((num_chunks, out_cols),
-                                       jnp.float32),
-        interpret=interpret,
-    )(atom_starts, tile_starts, block_chunks_flat, chunk_counts,
-      *operands)
+    @jax.custom_batching.custom_vmap
+    def run(vals, *shared):
+        tids, rest = (shared[0], shared[1:]) if emit == "tiles" \
+            else (None, shared)
+        lead = vals.shape[:-1]
+        out = launch(vals.reshape((-1,) + vals.shape[-1:]), tids, *rest)
+        return out.reshape(lead + out.shape[1:])
+
+    @run.def_vmap
+    def _lanes(axis_size, in_batched, vals, *shared):
+        if not any(in_batched[1:]):
+            return run(vals, *shared), True
+        # per-lane chunk structures (a vmapped cond batches every operand
+        # of its branches): one launch per lane
+        args = [x if b else jnp.broadcast_to(x, (axis_size,) + x.shape)
+                for x, b in zip((vals,) + shared, in_batched)]
+        return jax.lax.map(lambda a: run(*a), tuple(args)), True
+
+    return run(vals, *shared)

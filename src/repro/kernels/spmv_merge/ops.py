@@ -24,10 +24,9 @@ def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m
 
 
-@functools.partial(jax.jit, static_argnames=("num_rows", "nnz", "block_items",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("num_rows", "nnz", "block_items"))
 def _spmv_merge_path(row_offsets, col_indices, values, x, *, num_rows: int,
-                     nnz: int, block_items: int, interpret: bool):
+                     nnz: int, block_items: int):
     total = _round_up(max(num_rows + nnz, 1), block_items)
     stream_vals, stream_rows = _ref.merge_stream_ref(
         row_offsets, col_indices, values, x, num_rows, nnz, total)
@@ -38,11 +37,10 @@ def _spmv_merge_path(row_offsets, col_indices, values, x, *, num_rows: int,
     row_base = jnp.minimum(row_base, max(num_rows - 1, 0))
     return _kernel.spmv_merge_stream(stream_vals, stream_rows, row_base,
                                      num_rows=num_rows,
-                                     block_items=block_items,
-                                     interpret=interpret)
+                                     block_items=block_items)
 
 
-def _spmv_measure(A, x, nb: int, interpret: bool):
+def _spmv_measure(A, x, nb: int):
     """Measured-mode timing closure: one candidate plan on this very SpMV."""
     from repro.core.execute import execute_tile_reduce
     from repro.core.measure import time_fn
@@ -57,7 +55,7 @@ def _spmv_measure(A, x, nb: int, interpret: bool):
         def f(xv):
             return execute_tile_reduce(spec, part,
                                        lambda nz: vals[nz] * xv[cols[nz]],
-                                       path=plan.path, interpret=interpret)
+                                       path=plan.path)
 
         return time_fn(f, x, warmup=1, iters=3)
     return run
@@ -67,8 +65,7 @@ def spmv_merge_path(A, x, *, num_blocks: int | None = None,
                     block_items: int = 512,
                     schedule: Schedule | str | None = None,
                     execution_path: ExecutionPath | str = ExecutionPath.AUTO,
-                    measure=None,
-                    interpret: bool = True) -> jax.Array:
+                    measure=None) -> jax.Array:
     """Merge-path SpMV ``y = A @ x`` for a :class:`repro.sparse.CSR` matrix.
 
     ``num_blocks`` (if given) overrides ``block_items`` to target a specific
@@ -84,9 +81,7 @@ def spmv_merge_path(A, x, *, num_blocks: int | None = None,
     kernel — each physical block scalar-prefetches its chunk queue and walks
     it in-kernel; ``"pure"`` keeps the PR-1 fallbacks (chunk-granular merge
     stream for chunked, one merge stream per block otherwise).  Requires
-    concrete (non-traced) ``A.row_offsets``.  The container is CPU-only, so
-    ``interpret=True`` is the validated default; on real TPU pass
-    ``interpret=False``.
+    concrete (non-traced) ``A.row_offsets``.
 
     ``measure`` is the measured-cost feedback knob (docs/autotune.md):
     with ``schedule="auto"`` and ``REPRO_AUTOTUNE_MEASURE=1`` the
@@ -106,7 +101,7 @@ def spmv_merge_path(A, x, *, num_blocks: int | None = None,
             if callable(measure):
                 m = measure
             elif measure is not False and measurement_enabled():
-                m = _spmv_measure(A, x, nb, interpret)
+                m = _spmv_measure(A, x, nb)
             else:
                 m = None
             plan = select_plan(A.workspec(), nb, measure=m)
@@ -127,10 +122,8 @@ def spmv_merge_path(A, x, *, num_blocks: int | None = None,
                                       chunk_policy=policy or "lpt")
                 path = choose_execution_path(part, execution_path)
             if path == ExecutionPath.NATIVE:
-                vals, cols = A.values, A.col_indices
-                atom_fn = lambda nz: vals[nz] * x[cols[nz]]
-                return execute_tile_reduce(spec, part, atom_fn, path=path,
-                                           interpret=interpret)
+                return execute_tile_reduce(
+                    spec, part, A.values * x[A.col_indices], path=path)
             # pure fallback keeps PR-1 behavior: the kernel consumes a 1-D
             # merge stream; a chunked choice oversplits it into the
             # chunk-level grid (only the block granularity changes)
@@ -146,4 +139,4 @@ def spmv_merge_path(A, x, *, num_blocks: int | None = None,
                           128)
     return _spmv_merge_path(A.row_offsets, A.col_indices, A.values, x,
                             num_rows=num_rows, nnz=A.nnz,
-                            block_items=block_items, interpret=interpret)
+                            block_items=block_items)

@@ -17,7 +17,6 @@ import jax.numpy as jnp  # noqa: E402
 
 from repro.configs import ARCH_IDS, SHAPES, cells_for, get_config  # noqa
 from repro.launch import roofline as RL                            # noqa
-from repro.compat import use_ambient_mesh                          # noqa
 from repro.launch.mesh import make_production_mesh                 # noqa
 from repro.launch.specs import (decode_input_specs, pick_microbatches,  # noqa
                                 prefill_input_specs, train_input_specs)
@@ -56,12 +55,12 @@ def build_cell(cfg, case, mesh, n_micro):
 
         if cfg.frontend is not None:
             def fn(params, tokens, prefix):
-                with use_ambient_mesh(mesh):
+                with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
                     return prefill(params, cfg, tokens, prefix,
                                    dtype=jnp.bfloat16)
         else:
             def fn(params, tokens):
-                with use_ambient_mesh(mesh):
+                with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
                     return prefill(params, cfg, tokens, dtype=jnp.bfloat16)
         step = jax.jit(fn, out_shardings=(
             NamedSharding(mesh, P()), cache_sh))

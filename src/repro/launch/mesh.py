@@ -4,9 +4,9 @@ Defined as FUNCTIONS (not module-level constants) so importing this module
 never touches jax device state — smoke tests must keep seeing 1 CPU device;
 only ``dryrun.py`` forces 512 host devices via XLA_FLAGS before any import.
 
-Version compat: ``AxisType`` and ``make_mesh`` come from :mod:`repro.compat`
-(jax 0.4.x has neither ``jax.sharding.AxisType`` nor the ``axis_types=``
-kwarg); tests import them from here so they run on both API generations.
+Every mesh here has ``AxisType.Auto`` axes: ``jax.make_mesh`` now defaults
+to ``Explicit`` axes, under which ``with_sharding_constraint`` asserts a
+sharding instead of requesting it.
 
 Axes:
 * ``data`` — FSDP + batch data-parallel (16 chips: one v5e pod row)
@@ -17,8 +17,8 @@ Axes:
 from __future__ import annotations
 
 import jax
-
-from repro.compat import AxisType, make_mesh
+from jax import make_mesh
+from jax.sharding import AxisType
 
 __all__ = ["AxisType", "make_mesh", "make_production_mesh", "make_host_mesh",
            "make_graph_mesh"]

@@ -15,7 +15,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import get_ambient_mesh
 
 Params = Dict[str, Any]
 
@@ -35,8 +34,8 @@ def maybe_constrain(x: jax.Array, *spec) -> jax.Array:
     matter: GSPMD drops the batch sharding on mask/select chains built from
     iota (a measured 15x per-device blow-up of attention logits).
     """
-    mesh = get_ambient_mesh()
-    if mesh is None or mesh.empty:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return x
     cleaned = []
     for a in spec:

@@ -178,8 +178,7 @@ def moe_capacity(params: Params, x: jax.Array, *, num_experts: int,
 
 def moe_sorted(params: Params, x: jax.Array, *, num_experts: int, top_k: int,
                bm: int = 128, schedule: str = "group_mapped",
-               execution_path: str = "auto",
-               interpret: bool = True) -> Tuple[jax.Array, jax.Array]:
+               execution_path: str = "auto") -> Tuple[jax.Array, jax.Array]:
     """The paper's load-balanced dispatch: sort atoms by tile, pad to
     M-blocks, balanced segmented GEMM.  Drop-free.
 
@@ -222,8 +221,7 @@ def moe_sorted(params: Params, x: jax.Array, *, num_experts: int, top_k: int,
                     policy, p = segmm_ops.plan_policy(plan)
                     f = functools.partial(
                         segmm_ops.grouped_matmul, num_experts=num_experts,
-                        bm=bm, schedule=policy, execution_path=p,
-                        interpret=interpret)
+                        bm=bm, schedule=policy, execution_path=p)
                     return time_fn(f, atoms_in, atom_expert, params["w1"],
                                    warmup=1, iters=3)
         schedule = segmm_ops.resolve_schedule(atom_expert, num_experts,
@@ -232,20 +230,17 @@ def moe_sorted(params: Params, x: jax.Array, *, num_experts: int, top_k: int,
     h1 = segmm_ops.grouped_matmul(atoms_in, atom_expert, params["w1"],
                                   num_experts=num_experts, bm=bm,
                                   schedule=schedule,
-                                  execution_path=execution_path,
-                                  interpret=interpret)
+                                  execution_path=execution_path)
     h3 = segmm_ops.grouped_matmul(atoms_in, atom_expert, params["w3"],
                                   num_experts=num_experts, bm=bm,
                                   schedule=schedule,
-                                  execution_path=execution_path,
-                                  interpret=interpret)
+                                  execution_path=execution_path)
     h = jax.nn.silu(h1) * h3
     out_atoms = segmm_ops.grouped_matmul(h.astype(x.dtype), atom_expert,
                                          params["w2"],
                                          num_experts=num_experts, bm=bm,
                                          schedule=schedule,
-                                         execution_path=execution_path,
-                                         interpret=interpret)
+                                         execution_path=execution_path)
     weighted = out_atoms * topk_w.reshape(t * top_k, 1)
     out = jax.ops.segment_sum(weighted, atom_token, num_segments=t)
     return out.reshape(b, s, d).astype(x.dtype), aux

@@ -18,7 +18,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import use_ambient_mesh
 from repro.configs.base import ModelConfig
 from repro.models import cache_shape, decode_step
 
@@ -75,7 +74,7 @@ def make_serve_step(cfg: ModelConfig, mesh: Mesh, *, batch: int,
     tok_sh = NamedSharding(mesh, P(daxes if daxes else None, None))
 
     def step_fn(params, tokens, pos, cache):
-        with use_ambient_mesh(mesh):
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
             return decode_step(params, cfg, tokens, pos, cache, dtype=dtype)
 
     step = jax.jit(step_fn,

@@ -20,8 +20,13 @@ traversal):
   advance serves both kinds at no extra cost.  PageRank lanes ride a
   separate sum-combiner advance (the driver's power-iteration body) that
   runs under a *scalar* ``lax.cond`` — a stream with no live PageRank lane
-  never pays it (and vice versa for the relax).  Each lane's ``[V]`` value
-  row is its tentative distances (BFS/SSSP) or rank vector (PageRank).
+  never pays it (and vice versa for the relax).  The lanes share one
+  kernel launch (lanes are its leading grid axis), and every per-edge
+  array of the step is ``[lanes, E]`` with the lanes leading: per-edge
+  gathers go through :func:`repro.core.execute.lane_take`, since JAX's own
+  vmap of a gather puts the lanes minor, which XLA:TPU pads 16-fold.  Each
+  lane's ``[V]`` value row is its tentative distances (BFS/SSSP) or rank
+  vector (PageRank).
 * **Driver-exact recurrences.**  Each lane replays the exact loop body of
   its single-query driver (:func:`repro.sparse.graph.bfs` / ``sssp`` /
   ``pagerank``) over the same plan, so a retired lane's answer is
@@ -54,6 +59,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import ExecutionPath, Schedule
+from repro.core.execute import lane_take
 from repro.sparse.advance import (AdvancePlan, advance, advance_push,
                                   build_advance)
 from repro.sparse.graph import (Graph, INF, _active_edge_count, _directed,
@@ -161,8 +167,7 @@ class GraphServer:
                  max_iters: Optional[int] = None,
                  damping: float = 0.85, num_iters: int = 50,
                  tol: float = 0.0,
-                 measure=None,
-                 interpret: bool = True):
+                 measure=None):
         if graph.num_vertices == 0:
             raise ValueError("GraphServer needs a non-empty graph "
                              "(no valid query sources on 0 vertices)")
@@ -176,7 +181,7 @@ class GraphServer:
         self.direction = direction
         self.plan = plan if plan is not None else build_advance(
             graph, schedule=schedule, num_blocks=num_blocks, path=path,
-            workload="advance_serve", measure=measure, interpret=interpret)
+            workload="advance_serve", measure=measure)
         V = graph.num_vertices
         self._V = V
         self.max_iters = V if max_iters is None else int(max_iters)
@@ -216,48 +221,51 @@ class GraphServer:
             pushes=jnp.zeros((W,), jnp.int32))
 
     def _make_step(self):
-        plan, W, V = self.plan, self.lanes, self._V
         direction = self.direction
         max_iters, num_iters = self.max_iters, self.num_iters
         damping, tol = self.damping, self.tol
-        outdeg = plan.out_degrees.astype(jnp.float32)
-        src, psrc = plan.src, plan.push_src
-        w_pull, w_push = plan.weight, plan.push_weight
 
-        def lane_relax(value, frontier, unit, active_edges):
-            # One BFS/SSSP lane: the drivers' `_relax_directed` body with a
-            # per-lane unit-weight select (BFS == unit-weight Bellman-Ford,
-            # so SSSP lanes see exactly `value[src[e]] + weight[e]` — the
-            # same two f32 operands, same rounding, as advance_relax_min).
-            wl = jnp.where(unit, jnp.float32(1.0), w_pull)
-            wp = jnp.where(unit, jnp.float32(1.0), w_push)
-            cand, used_push = _directed(
-                plan, direction, active_edges,
-                lambda: advance_push(plan, frontier,
-                                     lambda e: value[psrc[e]] + wp[e],
-                                     combiner="min"),
-                lambda: advance(plan, frontier,
-                                lambda e: value[src[e]] + wl[e],
-                                combiner="min"))
-            new_value = jnp.minimum(value, cand)
-            return new_value, new_value < value, used_push
-
-        def lane_pagerank(pr):
-            # One PageRank lane: the driver's power-iteration body, pull
-            # direction (the driver's "auto" resolution on a full
-            # frontier), bit-for-bit.  The shared helpers pin per-op
-            # rounding behind optimization barriers — without them XLA
-            # fuses the update differently in the vmapped serving step
-            # than in the driver's while_loop and the bits drift.
-            share = _pagerank_share(pr, outdeg)
-            contrib = advance(plan, None, lambda e: share[src[e]],
-                              combiner="sum")
-            dangling = jnp.sum(jnp.where(outdeg > 0, 0.0, pr))
-            new_pr = _pagerank_update(contrib, dangling, damping, V)
-            return new_pr, jnp.abs(new_pr - pr).sum()
-
-        def step(b: QueryBatch) -> QueryBatch:
+        # The plan is the step's argument, not a closure: closed over by
+        # jax.jit, its arrays would be compiled into the step as constants.
+        def step(plan: AdvancePlan, b: QueryBatch) -> QueryBatch:
             self._step_traces.append(time.perf_counter())
+            W, V = b.value.shape
+            outdeg = plan.out_degrees.astype(jnp.float32)
+            src, psrc = plan.src, plan.push_src
+            w_pull, w_push = plan.weight, plan.push_weight
+
+            def lane_relax(value, frontier, unit, active_edges):
+                # One BFS/SSSP lane: the drivers' `_relax_directed` body with a
+                # per-lane unit-weight select (BFS == unit-weight Bellman-Ford,
+                # so SSSP lanes see exactly `value[src[e]] + weight[e]` — the
+                # same two f32 operands, same rounding, as advance_relax_min).
+                wl = jnp.where(unit, jnp.float32(1.0), w_pull)
+                wp = jnp.where(unit, jnp.float32(1.0), w_push)
+                cand, used_push = _directed(
+                    plan, direction, active_edges,
+                    lambda: advance_push(plan, frontier,
+                                         lane_take(value, psrc) + wp,
+                                         combiner="min"),
+                    lambda: advance(plan, frontier,
+                                    lane_take(value, src) + wl,
+                                    combiner="min"))
+                new_value = jnp.minimum(value, cand)
+                return new_value, new_value < value, used_push
+
+            def lane_pagerank(pr):
+                # One PageRank lane: the driver's power-iteration body, pull
+                # direction (the driver's "auto" resolution on a full
+                # frontier), bit-for-bit.  The shared helpers pin per-op
+                # rounding behind optimization barriers — without them XLA
+                # fuses the update differently in the vmapped serving step
+                # than in the driver's while_loop and the bits drift.
+                share = _pagerank_share(pr, outdeg)
+                contrib = advance(plan, None, lane_take(share, src),
+                                  combiner="sum")
+                dangling = jnp.sum(jnp.where(outdeg > 0, 0.0, pr))
+                new_pr = _pagerank_update(contrib, dangling, damping, V)
+                return new_pr, jnp.abs(new_pr - pr).sum()
+
             live = jnp.logical_and(b.active, ~b.done)
             is_pr = b.kind == KIND_PAGERANK
             dist_live = jnp.logical_and(live, ~is_pr)
@@ -332,10 +340,10 @@ class GraphServer:
         return step
 
     def _make_admit(self):
-        plan, V = self.plan, self._V
+        V = self._V
 
-        def admit(b: QueryBatch, clear, take, kind, source, qid
-                  ) -> QueryBatch:
+        def admit(plan: AdvancePlan, b: QueryBatch, clear, take, kind,
+                  source, qid) -> QueryBatch:
             # clear: [W] bool — retired lanes to free; take: [W] bool —
             # lanes to (re)initialize from kind/source/qid.  Pure content
             # writes: the batch's shapes never change, so the serving step
@@ -474,11 +482,12 @@ class GraphServer:
             self._lane_qid[lane] = q
 
         if clear.any() or take.any():
-            self.batch = self._jadmit(self.batch, jnp.asarray(clear),
+            self.batch = self._jadmit(self.plan, self.batch,
+                                      jnp.asarray(clear),
                                       jnp.asarray(take), jnp.asarray(kind),
                                       jnp.asarray(source), jnp.asarray(qid))
         if (self._lane_qid >= 0).any():
-            self.batch = self._jstep(self.batch)
+            self.batch = self._jstep(self.plan, self.batch)
             self.steps += 1
         return results
 

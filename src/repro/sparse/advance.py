@@ -53,7 +53,8 @@ Two refinements ride the same plan pair (this PR):
 from __future__ import annotations
 
 import dataclasses
-from typing import Callable, Optional
+import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -65,6 +66,7 @@ from repro.core import (ExecutionPath, Partition, Schedule,
                         estimate_direction_threshold,
                         execute_scatter_reduce, execute_tile_reduce,
                         make_partition)
+from repro.core.execute import AtomFn, lane_take
 from repro.core.work import WorkSpec
 
 #: Default physical blocks for graph advance (graphs in this repo's tests
@@ -104,6 +106,14 @@ def estimate_delta(weights) -> float:
     return float(max(np.float32(w.mean()), w.min()))
 
 
+@functools.partial(
+    jax.tree_util.register_dataclass,
+    data_fields=["spec", "src", "weight", "part", "push_spec", "dst",
+                 "push_weight", "push_src", "push_part", "out_degrees",
+                 "light_mask", "push_light_mask", "light_out_degrees"],
+    meta_fields=["schedule", "path", "push_schedule", "push_path",
+                 "num_vertices", "direction_threshold", "delta",
+                 "compact_capacity"])
 @dataclasses.dataclass(frozen=True)
 class AdvancePlan:
     """One-time inspector output for a graph's advance operator — a *pair*
@@ -115,8 +125,10 @@ class AdvancePlan:
     view: tiles = source vertices, atoms = out-edges; ``dst`` is each
     out-edge atom's destination (the scatter id), ``push_src`` its source
     tile (the frontier-mask gather, materialized once).  Built outside jit
-    (partitioning is a pre-launch inspector); consumed freely inside
-    ``lax.while_loop`` bodies, where its arrays become trace constants.
+    (partitioning is a pre-launch inspector).  A pytree: hand it to a
+    jitted function as an argument, so its arrays stay device buffers —
+    closed over by ``jax.jit`` they would be baked into the program as
+    constants — while its statics ride the treedef.
 
     ``direction_threshold`` is the modeled frontier (out-edge) density at
     which pull becomes cheaper than push
@@ -145,7 +157,6 @@ class AdvancePlan:
     num_vertices: int
     out_degrees: jax.Array    # [V] int32 (measured-density term)
     direction_threshold: float
-    interpret: bool = True
     # -- bucketed (delta-stepping) view: set by with_delta/build_advance ----
     delta: Optional[float] = None
     light_mask: Optional[jax.Array] = None       # [E] bool, pull edge order
@@ -278,8 +289,7 @@ def _resolve_direction_plan(spec: WorkSpec, schedule, path, num_blocks: int,
 
 def _direction_measure(spec: WorkSpec, gather: jax.Array, num_blocks: int,
                        direction: str, weight: jax.Array,
-                       num_vertices: int, dst: Optional[jax.Array],
-                       interpret: bool):
+                       num_vertices: int, dst: Optional[jax.Array]):
     """Default measured-mode timing closure for one direction's candidates.
 
     Times each candidate (schedule, path) plan on this graph's *actual*
@@ -307,14 +317,13 @@ def _direction_measure(spec: WorkSpec, gather: jax.Array, num_blocks: int,
                 return execute_scatter_reduce(
                     spec, part, lambda e: atom_fn(e, p), dst, num_vertices,
                     jnp.float32, path=plan.path, combiner="min",
-                    atom_mask=mask, interpret=interpret)
+                    atom_mask=mask)
         else:
             @jax.jit
             def f(p):
                 return execute_tile_reduce(
                     spec, part, lambda e: atom_fn(e, p), jnp.float32,
-                    path=plan.path, combiner="min", atom_mask=mask,
-                    interpret=interpret)
+                    path=plan.path, combiner="min", atom_mask=mask)
         return time_fn(f, potentials, warmup=1, iters=3)
     return run
 
@@ -335,8 +344,7 @@ def build_advance(graph, *, schedule: Schedule | str = "auto",
                   direction_threshold: Optional[float] = None,
                   delta: Optional[float | str] = None,
                   compact: Optional[bool | int | float] = None,
-                  measure=None,
-                  interpret: bool = True) -> AdvancePlan:
+                  measure=None) -> AdvancePlan:
     """Inspect a :class:`~repro.sparse.graph.Graph` into an AdvancePlan pair.
 
     One inspector call builds *both* directions: the pull partition over the
@@ -389,11 +397,11 @@ def build_advance(graph, *, schedule: Schedule | str = "auto",
         elif measurement_enabled():
             pull_measure = _direction_measure(
                 spec, pull.col_indices, num_blocks, "pull",
-                pull.values, graph.num_vertices, None, interpret)
+                pull.values, graph.num_vertices, None)
             push_measure = _direction_measure(
                 push_spec, push_ids, num_blocks, "push",
                 graph.csr.values, graph.num_vertices,
-                graph.csr.col_indices, interpret)
+                graph.csr.col_indices)
     return build_advance_views(
         pull_spec=spec, pull_src=pull.col_indices, pull_weight=pull.values,
         push_spec=push_spec, push_dst=graph.csr.col_indices,
@@ -402,8 +410,7 @@ def build_advance(graph, *, schedule: Schedule | str = "auto",
         schedule=schedule, num_blocks=num_blocks, path=path,
         workload=workload, direction_threshold=direction_threshold,
         delta=delta, compact=compact,
-        pull_measure=pull_measure, push_measure=push_measure,
-        interpret=interpret)
+        pull_measure=pull_measure, push_measure=push_measure)
 
 
 def build_advance_views(*, pull_spec: WorkSpec, pull_src: jax.Array,
@@ -419,8 +426,7 @@ def build_advance_views(*, pull_spec: WorkSpec, pull_src: jax.Array,
                         delta: Optional[float | str] = None,
                         compact: Optional[bool | int | float] = None,
                         pull_measure=None, push_measure=None,
-                        out_degrees: Optional[jax.Array] = None,
-                        interpret: bool = True) -> AdvancePlan:
+                        out_degrees: Optional[jax.Array] = None) -> AdvancePlan:
     """The view-level inspector core behind :func:`build_advance`.
 
     Takes the two work views directly (pull: tiles = destinations over
@@ -482,8 +488,7 @@ def build_advance_views(*, pull_spec: WorkSpec, pull_src: jax.Array,
         num_vertices=num_vertices,
         out_degrees=out_degrees.astype(jnp.int32),
         direction_threshold=float(direction_threshold),
-        compact_capacity=capacity,
-        interpret=interpret)
+        compact_capacity=capacity)
     if delta is not None:
         plan = plan.with_delta(None if delta == "auto" else delta)
     return plan
@@ -492,7 +497,8 @@ def build_advance_views(*, pull_spec: WorkSpec, pull_src: jax.Array,
 def _combined_mask(vertex_mask: Optional[jax.Array], gather: jax.Array,
                    edge_mask: Optional[jax.Array]) -> Optional[jax.Array]:
     """frontier-gather AND edge-subset mask (either may be absent)."""
-    atom_mask = None if vertex_mask is None else vertex_mask[gather]
+    atom_mask = (None if vertex_mask is None
+                 else lane_take(vertex_mask, gather))
     if edge_mask is None:
         return atom_mask
     return edge_mask if atom_mask is None else jnp.logical_and(atom_mask,
@@ -500,7 +506,7 @@ def _combined_mask(vertex_mask: Optional[jax.Array], gather: jax.Array,
 
 
 def advance(plan: AdvancePlan, frontier: Optional[jax.Array],
-            atom_fn: Callable[[jax.Array], jax.Array], *,
+            atom_fn: AtomFn, *,
             combiner: str = "sum",
             edge_mask: Optional[jax.Array] = None) -> jax.Array:
     """The pull-direction balanced advance: per-destination ``combiner``-
@@ -509,9 +515,10 @@ def advance(plan: AdvancePlan, frontier: Optional[jax.Array],
 
     ``frontier`` is a bool ``[V]`` vertex mask (``None`` = all active);
     ``atom_fn`` maps **in-edge atom ids** (pull order) to f32 candidate
-    values (Listing 5's loop body).  ``edge_mask`` (bool ``[E]``, pull edge
-    order) further restricts the atom set — the delta-stepping light/heavy
-    split (:meth:`AdvancePlan.edge_set_mask`).  Returns ``[V]`` f32;
+    values (Listing 5's loop body), or is those ``[E]`` values.
+    ``edge_mask`` (bool ``[E]``, pull edge order) further restricts the
+    atom set — the delta-stepping light/heavy split
+    (:meth:`AdvancePlan.edge_set_mask`).  Returns ``[V]`` f32;
     destinations with no active in-edge carry the combiner's identity.
     Routed through :func:`repro.core.execute.execute_tile_reduce`, so every
     schedule and both execution paths produce identical bits.
@@ -519,18 +526,19 @@ def advance(plan: AdvancePlan, frontier: Optional[jax.Array],
     atom_mask = _combined_mask(frontier, plan.src, edge_mask)
     return execute_tile_reduce(plan.spec, plan.part, atom_fn, jnp.float32,
                                path=plan.path, combiner=combiner,
-                               atom_mask=atom_mask, interpret=plan.interpret)
+                               atom_mask=atom_mask)
 
 
 def advance_push(plan: AdvancePlan, frontier: Optional[jax.Array],
-                 atom_fn: Callable[[jax.Array], jax.Array], *,
+                 atom_fn: AtomFn, *,
                  combiner: str = "sum",
                  edge_mask: Optional[jax.Array] = None) -> jax.Array:
     """The push-direction balanced advance (Listing 5's own orientation).
 
     ``atom_fn`` maps **out-edge atom ids** (push/forward order) to f32
-    candidate values; ``edge_mask`` (bool ``[E]``, push edge order) is the
-    delta-stepping light/heavy restriction.  The balanced executors walk
+    candidate values, or is those ``[E]`` values; ``edge_mask`` (bool
+    ``[E]``, push edge order) is the delta-stepping light/heavy
+    restriction.  The balanced executors walk
     the push partition (tiles = source vertices) producing
     frontier-compacted per-source value windows;
     :func:`repro.core.execute.scatter_value_windows` then combines them by
@@ -550,8 +558,7 @@ def advance_push(plan: AdvancePlan, frontier: Optional[jax.Array],
                                   plan.dst, plan.num_vertices, jnp.float32,
                                   path=plan.push_path, combiner=combiner,
                                   atom_mask=atom_mask,
-                                  compact_capacity=plan.compact_capacity,
-                                  interpret=plan.interpret)
+                                  compact_capacity=plan.compact_capacity)
 
 
 def _check_direction(direction: str) -> str:
@@ -580,11 +587,10 @@ def advance_relax_min(plan: AdvancePlan, potentials: jax.Array,
     edge_mask = plan.edge_set_mask(edges, _check_direction(direction))
     if direction == "push":
         src, w = plan.push_src, plan.push_weight
-        return advance_push(plan, frontier,
-                            lambda e: potentials[src[e]] + w[e],
+        return advance_push(plan, frontier, lane_take(potentials, src) + w,
                             combiner="min", edge_mask=edge_mask)
     src, w = plan.src, plan.weight
-    return advance(plan, frontier, lambda e: potentials[src[e]] + w[e],
+    return advance(plan, frontier, lane_take(potentials, src) + w,
                    combiner="min", edge_mask=edge_mask)
 
 
@@ -619,12 +625,11 @@ def advance_src_argmin(plan: AdvancePlan, frontier: jax.Array, *,
             f"below 2**24 vertices (got {plan.num_vertices})")
     if _check_direction(direction) == "push":
         src = plan.push_src
-        cand = advance_push(plan, frontier,
-                            lambda e: src[e].astype(jnp.float32),
+        cand = advance_push(plan, frontier, src.astype(jnp.float32),
                             combiner="min")
     else:
         src = plan.src
-        cand = advance(plan, frontier, lambda e: src[e].astype(jnp.float32),
+        cand = advance(plan, frontier, src.astype(jnp.float32),
                        combiner="min")
     return jnp.where(jnp.isfinite(cand), cand, -1.0).astype(jnp.int32)
 

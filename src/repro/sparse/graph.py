@@ -36,14 +36,15 @@ into wrong-but-plausible results).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import opt_barrier
 from repro.core import ExecutionPath, Schedule
+from repro.core.execute import lane_take
 from repro.sparse.advance import (AdvancePlan, advance, advance_frontier,
                                   advance_push, advance_relax_min,
                                   advance_src_argmin, build_advance,
@@ -97,24 +98,22 @@ class Graph:
                      num_blocks: Optional[int] = None,
                      path: ExecutionPath | str = ExecutionPath.AUTO,
                      workload: str = "advance",
-                     direction_threshold: Optional[float] = None,
-                     interpret: bool = True) -> AdvancePlan:
+                     direction_threshold: Optional[float] = None) -> AdvancePlan:
         """One-time inspector: see :func:`repro.sparse.advance.build_advance`."""
         return build_advance(self, schedule=schedule, num_blocks=num_blocks,
                              path=path, workload=workload,
-                             direction_threshold=direction_threshold,
-                             interpret=interpret)
+                             direction_threshold=direction_threshold)
 
 
 def _resolve_plan(graph: Graph, plan: Optional[AdvancePlan],
-                  schedule, num_blocks, path, interpret,
+                  schedule, num_blocks, path,
                   workload: str = "advance", delta=None,
                   compact=None) -> AdvancePlan:
     if plan is not None:
         return plan
     return build_advance(graph, schedule=schedule, num_blocks=num_blocks,
                          path=path, workload=workload, delta=delta,
-                         compact=compact, interpret=interpret)
+                         compact=compact)
 
 
 def _wants_sharded(plan, mesh) -> bool:
@@ -126,7 +125,7 @@ def _wants_sharded(plan, mesh) -> bool:
 
 
 def _resolve_sharded_plan(graph: Graph, plan, mesh, schedule, num_blocks,
-                          path, interpret, workload: str = "advance",
+                          path, workload: str = "advance",
                           delta=None, compact=None, shard_schedule=None):
     """The sharded sibling of :func:`_resolve_plan` (lazy import: the shard
     module pulls in mesh/collective machinery single-device users never
@@ -141,7 +140,7 @@ def _resolve_sharded_plan(graph: Graph, plan, mesh, schedule, num_blocks,
     return _shard, _shard.build_sharded_advance(
         graph, mesh, schedule=schedule, num_blocks=num_blocks, path=path,
         workload=workload, shard_schedule=shard_schedule, delta=delta,
-        compact=compact, interpret=interpret)
+        compact=compact)
 
 
 def _check_driver_direction(direction: str) -> str:
@@ -228,8 +227,7 @@ def sssp(graph: Graph, source: int, *, max_iters: Optional[int] = None,
          direction: str = "auto",
          algorithm: str = "bellman_ford",
          delta: Optional[float] = None,
-         return_direction_counts: bool = False,
-         interpret: bool = True):
+         return_direction_counts: bool = False):
     """Single-source shortest path; returns distances [V] (inf = unreached).
 
     ``algorithm="bellman_ford"`` (default) is the frontier-driven
@@ -265,11 +263,10 @@ def sssp(graph: Graph, source: int, *, max_iters: Optional[int] = None,
                               num_blocks=num_blocks, path=path, plan=plan,
                               mesh=mesh, shard_schedule=shard_schedule,
                               direction=direction,
-                              return_direction_counts=return_direction_counts,
-                              interpret=interpret)
+                              return_direction_counts=return_direction_counts)
     if _wants_sharded(plan, mesh):
         _shard, splan = _resolve_sharded_plan(graph, plan, mesh, schedule,
-                                              num_blocks, path, interpret,
+                                              num_blocks, path,
                                               shard_schedule=shard_schedule)
         return _shard.sharded_sssp(
             splan, source, max_iters=max_iters, direction=direction,
@@ -277,8 +274,23 @@ def sssp(graph: Graph, source: int, *, max_iters: Optional[int] = None,
     V = graph.num_vertices
     _validate_sources(source, V)
     max_iters = V if max_iters is None else max_iters
-    aplan = _resolve_plan(graph, plan, schedule, num_blocks, path, interpret)
+    aplan = _resolve_plan(graph, plan, schedule, num_blocks, path)
+    dist, counts = _sssp_loop(aplan, jnp.asarray(source, jnp.int32),
+                              max_iters=int(max_iters), direction=direction)
+    if return_direction_counts:
+        return dist, counts
+    return dist
 
+
+# The traversal loops below run under jax.jit with the plan as an argument:
+# one compiled program per plan shape serves every source, and the plan's
+# arrays stay device buffers instead of being traced into a new program on
+# every call.
+@functools.partial(jax.jit, static_argnames=("max_iters", "direction"))
+def _sssp_loop(aplan: AdvancePlan, source: jax.Array, *, max_iters: int,
+               direction: str):
+    """Frontier Bellman-Ford; returns ``(dist, (push, pull) counts)``."""
+    V = aplan.num_vertices
     dist0 = jnp.full((V,), INF).at[source].set(0.0)
     frontier0 = jnp.zeros((V,), bool).at[source].set(True)
 
@@ -298,9 +310,7 @@ def sssp(graph: Graph, source: int, *, max_iters: Optional[int] = None,
     iters, dist, _, _, pushes = jax.lax.while_loop(
         cond, body, (0, dist0, frontier0,
                      _active_edge_count(aplan, frontier0), jnp.int32(0)))
-    if return_direction_counts:
-        return dist, jnp.stack([pushes, jnp.int32(iters) - pushes])
-    return dist
+    return dist, jnp.stack([pushes, jnp.int32(iters) - pushes])
 
 
 def _bucket_of(dist: jax.Array, delta: float) -> jax.Array:
@@ -321,8 +331,7 @@ def delta_stepping(graph: Graph, source: int, *,
                    shard_schedule: Optional[str] = None,
                    direction: str = "auto",
                    compact: Optional[bool | int | float] = True,
-                   return_direction_counts: bool = False,
-                   interpret: bool = True):
+                   return_direction_counts: bool = False):
     """Delta-stepping SSSP (Meyer & Sanders) on the advance plan pair.
 
     Distances are partitioned into buckets of width ``delta``
@@ -368,7 +377,7 @@ def delta_stepping(graph: Graph, source: int, *,
     _check_driver_direction(direction)
     if _wants_sharded(plan, mesh):
         _shard, splan = _resolve_sharded_plan(
-            graph, plan, mesh, schedule, num_blocks, path, interpret,
+            graph, plan, mesh, schedule, num_blocks, path,
             workload="advance_delta",
             delta=delta if delta is not None else "auto", compact=compact,
             shard_schedule=shard_schedule)
@@ -378,19 +387,14 @@ def delta_stepping(graph: Graph, source: int, *,
             return_direction_counts=return_direction_counts)
     V = graph.num_vertices
     _validate_sources(source, V)
-    aplan = _resolve_plan(graph, plan, schedule, num_blocks, path, interpret,
+    aplan = _resolve_plan(graph, plan, schedule, num_blocks, path,
                           workload="advance_delta",
                           delta=delta if delta is not None else "auto",
                           compact=compact)
     if aplan.delta is None or (delta is not None
                                and float(delta) != aplan.delta):
         aplan = aplan.with_delta(delta)
-    width = aplan.delta
     max_outer = (V + 2) if max_iters is None else max_iters
-    inner_cap = V + 1
-
-    light_out = aplan.light_out_degrees
-    heavy_out = aplan.out_degrees - light_out
 
     # Per-phase compaction capacity: a light-bucket advance can never
     # activate more atoms than the light edge set holds (that count is the
@@ -399,17 +403,40 @@ def delta_stepping(graph: Graph, source: int, *,
     # bucket frontiers stream tighter gather-compacted windows.  The
     # executor's measured-count ``lax.cond`` still arbitrates per advance,
     # so a mis-sized capacity costs streamed volume, never bits.
-    light_plan = heavy_plan = aplan
+    light_cap = heavy_cap = aplan.compact_capacity
     if aplan.compact_capacity is not None and aplan.num_edges:
         # numpy on the plan's own (concrete, inspector-built) degree array:
         # the whole driver may be wrapped in jax.jit, where a jnp.sum here
         # would become a tracer and could not size a static capacity
         light_edges = int(np.asarray(aplan.light_out_degrees).sum())
         heavy_edges = aplan.num_edges - light_edges
-        light_plan = aplan.with_compact_capacity(
-            min(aplan.compact_capacity, max(light_edges, 1)))
-        heavy_plan = aplan.with_compact_capacity(
-            min(aplan.compact_capacity, max(heavy_edges, 1)))
+        light_cap = min(aplan.compact_capacity, max(light_edges, 1))
+        heavy_cap = min(aplan.compact_capacity, max(heavy_edges, 1))
+    dist, counts = _delta_loop(aplan, jnp.asarray(source, jnp.int32),
+                               max_outer=int(max_outer), direction=direction,
+                               light_cap=light_cap, heavy_cap=heavy_cap)
+    if return_direction_counts:
+        return dist, counts
+    return dist
+
+
+@functools.partial(jax.jit, static_argnames=("max_outer", "direction",
+                                             "light_cap", "heavy_cap"))
+def _delta_loop(aplan: AdvancePlan, source: jax.Array, *, max_outer: int,
+                direction: str, light_cap: Optional[int],
+                heavy_cap: Optional[int]):
+    """Delta-stepping bucket loops; returns ``(dist, (push, pull) counts)``.
+
+    ``light_cap``/``heavy_cap`` are the light and heavy phases' push
+    compaction capacities (``None`` where the plan compacts nothing).
+    """
+    V = aplan.num_vertices
+    width = aplan.delta
+    inner_cap = V + 1
+    light_plan = aplan.with_compact_capacity(light_cap)
+    heavy_plan = aplan.with_compact_capacity(heavy_cap)
+    light_out = aplan.light_out_degrees
+    heavy_out = aplan.out_degrees - light_out
 
     def _active(mask, out_deg):
         return jnp.sum(jnp.where(mask, out_deg, 0)).astype(jnp.int32)
@@ -494,11 +521,11 @@ def delta_stepping(graph: Graph, source: int, *,
 
     _, dist, _, counts = jax.lax.while_loop(
         mop_cond, mop_body, (0, dist, needs, counts))
-    if return_direction_counts:
-        return dist, counts
-    return dist
+    return dist, counts
 
 
+@functools.partial(jax.jit, static_argnames=("max_iters", "direction",
+                                             "return_parents"))
 def _bfs_loop(aplan: AdvancePlan, source: jax.Array, max_iters: int,
               direction: str, return_parents: bool):
     """Shared BFS while-loop (single-source; vmap-able over ``source``).
@@ -564,8 +591,7 @@ def bfs(graph: Graph, source: int, *, max_iters: Optional[int] = None,
         shard_schedule: Optional[str] = None,
         return_parents: bool = False,
         direction: str = "auto",
-        return_direction_counts: bool = False,
-        interpret: bool = True):
+        return_direction_counts: bool = False):
     """BFS depth labels [V] (-1 = unreached); same advance, unit weights.
 
     ``return_parents=True`` additionally returns parent pointers [V]
@@ -589,7 +615,7 @@ def bfs(graph: Graph, source: int, *, max_iters: Optional[int] = None,
     _check_driver_direction(direction)
     if _wants_sharded(plan, mesh):
         _shard, splan = _resolve_sharded_plan(graph, plan, mesh, schedule,
-                                              num_blocks, path, interpret,
+                                              num_blocks, path,
                                               shard_schedule=shard_schedule)
         return _shard.sharded_bfs(
             splan, source, max_iters=max_iters,
@@ -598,9 +624,10 @@ def bfs(graph: Graph, source: int, *, max_iters: Optional[int] = None,
     V = graph.num_vertices
     _validate_sources(source, V)
     max_iters = V if max_iters is None else max_iters
-    aplan = _resolve_plan(graph, plan, schedule, num_blocks, path, interpret)
+    aplan = _resolve_plan(graph, plan, schedule, num_blocks, path)
 
-    depth, parent, counts = _bfs_loop(aplan, source, max_iters, direction,
+    depth, parent, counts = _bfs_loop(aplan, jnp.asarray(source, jnp.int32),
+                                      int(max_iters), direction,
                                       return_parents)
     out = (depth,)
     if return_parents:
@@ -617,8 +644,7 @@ def bfs_multi(graph: Graph, sources, *, max_iters: Optional[int] = None,
               plan: Optional[AdvancePlan] = None,
               mesh=None,
               shard_schedule: Optional[str] = None,
-              direction: str = "pull",
-              interpret: bool = True) -> jax.Array:
+              direction: str = "pull") -> jax.Array:
     """Batched multi-source BFS: depth labels ``[S, V]`` for ``sources[s]``.
 
     One plan pair serves the whole batch — the inspector runs once and
@@ -639,18 +665,18 @@ def bfs_multi(graph: Graph, sources, *, max_iters: Optional[int] = None,
     _check_driver_direction(direction)
     if _wants_sharded(plan, mesh):
         _shard, splan = _resolve_sharded_plan(graph, plan, mesh, schedule,
-                                              num_blocks, path, interpret,
+                                              num_blocks, path,
                                               shard_schedule=shard_schedule)
         return _shard.sharded_bfs_multi(splan, sources, max_iters=max_iters,
                                         direction=direction)
     V = graph.num_vertices
     _validate_sources(sources, V, what="bfs_multi sources")
     max_iters = V if max_iters is None else max_iters
-    aplan = _resolve_plan(graph, plan, schedule, num_blocks, path, interpret)
+    aplan = _resolve_plan(graph, plan, schedule, num_blocks, path)
     sources = jnp.asarray(sources, jnp.int32)
 
     def run(src):
-        depth, _, _ = _bfs_loop(aplan, src, max_iters, direction,
+        depth, _, _ = _bfs_loop(aplan, src, int(max_iters), direction,
                                 return_parents=False)
         return depth
 
@@ -659,7 +685,7 @@ def bfs_multi(graph: Graph, sources, *, max_iters: Optional[int] = None,
 
 def _pagerank_share(pr: jax.Array, outdeg: jax.Array) -> jax.Array:
     """Degree-normalized contribution vector (dangling rows emit zero)."""
-    return opt_barrier(
+    return jax.lax.optimization_barrier(
         jnp.where(outdeg > 0, pr / jnp.maximum(outdeg, 1.0), 0.0))
 
 
@@ -677,9 +703,9 @@ def _pagerank_update(contrib: jax.Array, dangling: jax.Array,
     rounded op sequence everywhere.  :func:`_pagerank_share` pins the
     share vector for the same reason.
     """
-    contrib, dangling = opt_barrier((contrib, dangling))
-    total = opt_barrier(contrib + dangling / V)
-    scaled = opt_barrier(damping * total)
+    contrib, dangling = jax.lax.optimization_barrier((contrib, dangling))
+    total = jax.lax.optimization_barrier(contrib + dangling / V)
+    scaled = jax.lax.optimization_barrier(damping * total)
     return (1.0 - damping) / V + scaled
 
 
@@ -691,8 +717,7 @@ def pagerank(graph: Graph, *, damping: float = 0.85, num_iters: int = 50,
              plan: Optional[AdvancePlan] = None,
              mesh=None,
              shard_schedule: Optional[str] = None,
-             direction: str = "auto",
-             interpret: bool = True) -> jax.Array:
+             direction: str = "auto") -> jax.Array:
     """Power-iteration PageRank [V] through the balanced advance.
 
     The per-iteration kernel is a full (unmasked) sum-combiner advance —
@@ -715,7 +740,7 @@ def pagerank(graph: Graph, *, damping: float = 0.85, num_iters: int = 50,
     direction = "pull" if direction == "auto" else direction
     if _wants_sharded(plan, mesh):
         _shard, splan = _resolve_sharded_plan(graph, plan, mesh, schedule,
-                                              num_blocks, path, interpret,
+                                              num_blocks, path,
                                               workload="reduce",
                                               shard_schedule=shard_schedule)
         return _shard.sharded_pagerank(splan, damping=damping,
@@ -726,12 +751,25 @@ def pagerank(graph: Graph, *, damping: float = 0.85, num_iters: int = 50,
         return jnp.zeros((0,), jnp.float32)
     # full-frontier sum-advance: no mask load/select per atom, so "auto"
     # scores the plain "reduce" cost family, not the masked-advance one
-    aplan = _resolve_plan(graph, plan, schedule, num_blocks, path, interpret,
+    aplan = _resolve_plan(graph, plan, schedule, num_blocks, path,
                           workload="reduce")
-    outdeg = graph.out_degrees().astype(jnp.float32)
-    src = aplan.push_src if direction == "push" else aplan.src
+    return _pagerank_loop(aplan, graph.out_degrees().astype(jnp.float32),
+                          damping=float(damping), num_iters=int(num_iters),
+                          tol=float(tol), direction=direction)
 
-    pr0 = jnp.full((V,), 1.0 / V, jnp.float32)
+
+# The loop runs under jit, not eagerly: XLA lowers the sum-advance's
+# reduction differently for an eagerly dispatched while_loop than for a
+# jit-compiled one (even with the barrier-pinned update), and the serving
+# layer's jitted step must reproduce driver bits exactly.  Compiling here
+# puts both in the same regime (see serve/graph.py).  The plan is an
+# argument, not a closure, so its arrays are not baked in as constants.
+@functools.partial(jax.jit, static_argnames=("damping", "num_iters", "tol",
+                                             "direction"))
+def _pagerank_loop(aplan: AdvancePlan, outdeg: jax.Array, *, damping: float,
+                   num_iters: int, tol: float, direction: str) -> jax.Array:
+    V = aplan.num_vertices
+    src = aplan.push_src if direction == "push" else aplan.src
 
     def cond(state):
         i, _, delta = state
@@ -740,21 +778,17 @@ def pagerank(graph: Graph, *, damping: float = 0.85, num_iters: int = 50,
     def body(state):
         i, pr, _ = state
         share = _pagerank_share(pr, outdeg)
-        atom_fn = lambda e: share[src[e]]
         if direction == "push":
-            contrib = advance_push(aplan, None, atom_fn, combiner="sum")
+            contrib = advance_push(aplan, None, lane_take(share, src),
+                                   combiner="sum")
         else:
-            contrib = advance(aplan, None, atom_fn, combiner="sum")
+            contrib = advance(aplan, None, lane_take(share, src),
+                              combiner="sum")
         dangling = jnp.sum(jnp.where(outdeg > 0, 0.0, pr))
         new_pr = _pagerank_update(contrib, dangling, damping, V)
         return i + 1, new_pr, jnp.abs(new_pr - pr).sum()
 
-    # The loop runs under jit, not eagerly: XLA lowers the sum-advance's
-    # reduction differently for an eagerly dispatched while_loop than for
-    # a jit-compiled one (even with the barrier-pinned update), and the
-    # serving layer's jitted step must reproduce driver bits exactly.
-    # Compiling here puts both in the same regime (see serve/graph.py).
-    run = jax.jit(lambda p0: jax.lax.while_loop(
-        cond, body, (0, p0, jnp.float32(jnp.inf))))
-    _, pr, _ = run(pr0)
+    pr0 = jnp.full((V,), 1.0 / V, jnp.float32)
+    _, pr, _ = jax.lax.while_loop(cond, body,
+                                  (0, pr0, jnp.float32(jnp.inf)))
     return pr

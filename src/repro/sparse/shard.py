@@ -80,9 +80,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core import (ExecutionPath, Schedule, choose_execution_path,
                         estimate_compact_capacity,
                         estimate_direction_threshold,
@@ -478,6 +477,23 @@ class ShardedAdvancePlan:
                 "glob": {"glob2pad": self.glob2pad,
                          "pad2glob": self.pad2glob}}
 
+    def on_mesh(self) -> "ShardedAdvancePlan":
+        """The same plan with every per-shard leaf placed on its own device
+        of the mesh (and the permutation replicated), so no driver call
+        moves the graph from one device to the others."""
+        split = NamedSharding(self.mesh, P(self.axis))
+        put = lambda tree: jax.device_put(tree, split)
+        return dataclasses.replace(
+            self, arrays=put(dict(self.arrays)),
+            pull_part_leaves=put(self.pull_part_leaves),
+            push_part_leaves=put(self.push_part_leaves),
+            pull_spec_leaves=put(self.pull_spec_leaves),
+            push_spec_leaves=put(self.push_spec_leaves),
+            glob2pad=jax.device_put(self.glob2pad,
+                                    NamedSharding(self.mesh, P())),
+            pad2glob=jax.device_put(self.pad2glob,
+                                    NamedSharding(self.mesh, P())))
+
     def with_delta(self, delta: Optional[float] = None) -> "ShardedAdvancePlan":
         """Attach the light/heavy bucket split to every shard.
 
@@ -514,7 +530,8 @@ class ShardedAdvancePlan:
             light_mask=arrays["light_mask"][0],
             push_light_mask=arrays["push_light_mask"][0],
             light_out_degrees=arrays["light_out_degrees"][0])
-        return dataclasses.replace(self, template=template, arrays=arrays)
+        return dataclasses.replace(self, template=template,
+                                   arrays=arrays).on_mesh()
 
 
 def _local_plan(splan: ShardedAdvancePlan, data):
@@ -574,8 +591,7 @@ def build_sharded_advance(graph, num_shards=None, *,
                           direction_threshold: Optional[float] = None,
                           delta: Optional[float | str] = None,
                           compact: Optional[bool | int | float] = None,
-                          measure=None,
-                          interpret: bool = True) -> ShardedAdvancePlan:
+                          measure=None) -> ShardedAdvancePlan:
     """Inspect a graph into a :class:`ShardedAdvancePlan`.
 
     ``num_shards`` accepts an int (shards = devices on a fresh 1-axis graph
@@ -772,7 +788,7 @@ def build_sharded_advance(graph, num_shards=None, *,
             path=path, workload=workload,
             direction_threshold=float(direction_threshold),
             compact=compact_resolved,
-            out_degrees=jnp.asarray(out_deg), interpret=interpret)
+            out_degrees=jnp.asarray(out_deg))
         shard_plans.append(plan)
         pull_valids.append(jnp.asarray(pvalid))
         push_valids.append(jnp.asarray(qvalid))
@@ -817,7 +833,7 @@ def build_sharded_advance(graph, num_shards=None, *,
         glob2pad=jnp.asarray(glob2pad), pad2glob=jnp.asarray(pad2glob))
     if delta is not None:
         splan = splan.with_delta(None if delta == "auto" else float(delta))
-    return splan
+    return splan.on_mesh()
 
 
 # ---------------------------------------------------------------------------
@@ -829,8 +845,7 @@ def _pull_local(splan, lp, frontier_full, atom_fn, *, combiner, edge_mask):
     atom_mask = _combined_mask(frontier_full, lp.src, edge_mask)
     out = execute_sharded_tile_reduce(
         lp.spec, lp.part, atom_fn, jnp.float32, axis_name=splan.axis,
-        path=lp.path, combiner=combiner, atom_mask=atom_mask,
-        interpret=lp.interpret)
+        path=lp.path, combiner=combiner, atom_mask=atom_mask)
     return out[:splan.shard_size]
 
 
@@ -841,7 +856,7 @@ def _push_local(splan, lp, frontier_full, atom_fn, *, combiner, edge_mask):
         lp.push_spec, lp.push_part, atom_fn, lp.dst, lp.num_vertices,
         jnp.float32, axis_name=splan.axis, path=lp.push_path,
         combiner=combiner, atom_mask=atom_mask,
-        compact_capacity=lp.compact_capacity, interpret=lp.interpret)
+        compact_capacity=lp.compact_capacity)
     lo = jax.lax.axis_index(splan.axis) * splan.shard_size
     return jax.lax.dynamic_slice(full, (lo,), (splan.shard_size,))
 
@@ -868,17 +883,14 @@ def _relax_local(splan, lp, pvalid, qvalid, direction, dist_full,
                  frontier_full, active_edges, edges: str = "all"):
     """One direction-resolved local min-relax; returns (cand, used_push)."""
     def push():
-        src, w = lp.push_src, lp.push_weight
         return _push_local(splan, lp, frontier_full,
-                           lambda e: dist_full[src[e]] + w[e],
+                           dist_full[lp.push_src] + lp.push_weight,
                            combiner="min",
                            edge_mask=_subset_mask(lp, "push", edges, qvalid))
 
     def pull():
-        src, w = lp.src, lp.weight
         return _pull_local(splan, lp, frontier_full,
-                           lambda e: dist_full[src[e]] + w[e],
-                           combiner="min",
+                           dist_full[lp.src] + lp.weight, combiner="min",
                            edge_mask=_subset_mask(lp, "pull", edges, pvalid))
 
     return _directed_sharded(splan, direction, active_edges, push, pull)
@@ -887,6 +899,9 @@ def _relax_local(splan, lp, pvalid, qvalid, direction, dist_full,
 # ---------------------------------------------------------------------------
 # Drivers
 # ---------------------------------------------------------------------------
+# Each driver runs its shard_map under jax.jit: called eagerly, shard_map
+# dispatches its body op by op and compiles about a hundred small programs
+# per call.
 
 def _make_bfs_fn(splan: ShardedAdvancePlan, max_iters: int, direction: str,
                  return_parents: bool):
@@ -916,17 +931,13 @@ def _make_bfs_fn(splan: ShardedAdvancePlan, max_iters: int, direction: str,
             full_f = jax.lax.all_gather(frontier_l, axis, tiled=True)
             if return_parents:
                 def push():
-                    srcs = lp.push_src
                     return _push_local(
-                        splan, lp, full_f,
-                        lambda e: srcs[e].astype(jnp.float32),
+                        splan, lp, full_f, lp.push_src.astype(jnp.float32),
                         combiner="min", edge_mask=qvalid)
 
                 def pull():
-                    srcs = lp.src
                     return _pull_local(
-                        splan, lp, full_f,
-                        lambda e: srcs[e].astype(jnp.float32),
+                        splan, lp, full_f, lp.src.astype(jnp.float32),
                         combiner="min", edge_mask=pvalid)
 
                 cand, used_push = _directed_sharded(
@@ -969,10 +980,10 @@ def _make_bfs_fn(splan: ShardedAdvancePlan, max_iters: int, direction: str,
                                p2g[jnp.maximum(parent, 0)], jnp.int32(-1))
         return state[1], parent, jnp.stack([pushes, iters - pushes])
 
-    return shard_map(
+    return jax.jit(jax.shard_map(
         body_fn, mesh=splan.mesh, in_specs=(_data_specs(axis), P()),
         out_specs=(P(axis), P(axis) if return_parents else P(), P()),
-        check=False)
+        check_vma=False))
 
 
 def sharded_bfs(splan: ShardedAdvancePlan, source, *,
@@ -1074,9 +1085,9 @@ def sharded_sssp(splan: ShardedAdvancePlan, source, *,
         iters, pushes = jnp.int32(state[0]), state[4]
         return state[1], jnp.stack([pushes, iters - pushes])
 
-    run = shard_map(body_fn, mesh=splan.mesh,
-                    in_specs=(_data_specs(axis), P()),
-                    out_specs=(P(axis), P()), check=False)
+    run = jax.jit(jax.shard_map(body_fn, mesh=splan.mesh,
+                                in_specs=(_data_specs(axis), P()),
+                                out_specs=(P(axis), P()), check_vma=False))
     dist_pad, counts = run(splan.data(), jnp.asarray(source, jnp.int32))
     dist = splan.to_global(dist_pad)
     if return_direction_counts:
@@ -1202,9 +1213,9 @@ def sharded_delta_stepping(splan: ShardedAdvancePlan, source, *,
             mop_cond, mop_body, (0, dist_l, needs_l, counts, nneeds))
         return dist_l, counts
 
-    run = shard_map(body_fn, mesh=splan.mesh,
-                    in_specs=(_data_specs(axis), P()),
-                    out_specs=(P(axis), P()), check=False)
+    run = jax.jit(jax.shard_map(body_fn, mesh=splan.mesh,
+                                in_specs=(_data_specs(axis), P()),
+                                out_specs=(P(axis), P()), check_vma=False))
     dist_pad, counts = run(splan.data(), jnp.asarray(source, jnp.int32))
     dist = splan.to_global(dist_pad)
     if return_direction_counts:
@@ -1248,14 +1259,11 @@ def sharded_pagerank(splan: ShardedAdvancePlan, *, damping: float = 0.85,
             share_l = _pagerank_share(pr_l, outdeg)
             full_share = jax.lax.all_gather(share_l, axis, tiled=True)
             if direction == "push":
-                srcs = lp.push_src
                 contrib = _push_local(splan, lp, None,
-                                      lambda e: full_share[srcs[e]],
+                                      full_share[lp.push_src],
                                       combiner="sum", edge_mask=qvalid)
             else:
-                srcs = lp.src
-                contrib = _pull_local(splan, lp, None,
-                                      lambda e: full_share[srcs[e]],
+                contrib = _pull_local(splan, lp, None, full_share[lp.src],
                                       combiner="sum", edge_mask=pvalid)
             dangling = jax.lax.psum(
                 jnp.sum(jnp.where(outdeg > 0, 0.0, pr_l)), axis)
@@ -1268,6 +1276,7 @@ def sharded_pagerank(splan: ShardedAdvancePlan, *, damping: float = 0.85,
                                         (0, pr0, jnp.float32(jnp.inf)))
         return pr_l
 
-    run = shard_map(body_fn, mesh=splan.mesh, in_specs=(_data_specs(axis),),
-                    out_specs=P(axis), check=False)
+    run = jax.jit(jax.shard_map(body_fn, mesh=splan.mesh,
+                                in_specs=(_data_specs(axis),),
+                                out_specs=P(axis), check_vma=False))
     return splan.to_global(run(splan.data()))

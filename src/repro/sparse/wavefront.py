@@ -158,8 +158,7 @@ def build_wavefront(dag: Graph, *,
                     num_blocks: Optional[int] = None,
                     path: ExecutionPath | str = ExecutionPath.AUTO,
                     workload: str = "wavefront",
-                    measure=None,
-                    interpret: bool = True) -> WavefrontPlan:
+                    measure=None) -> WavefrontPlan:
     """Inspect a dependency DAG into a :class:`WavefrontPlan`.
 
     One call validates acyclicity (host-side Kahn leveling — a cycle
@@ -175,8 +174,7 @@ def build_wavefront(dag: Graph, *,
                                   dag.num_vertices)
     num_levels = int(level_of.max()) + 1 if level_of.size else 0
     plan = build_advance(dag, schedule=schedule, num_blocks=num_blocks,
-                         path=path, workload=workload, measure=measure,
-                         interpret=interpret)
+                         path=path, workload=workload, measure=measure)
     counts = np.bincount(level_of, minlength=max(num_levels, 1)) \
         if level_of.size else np.zeros(0, np.int64)
     return WavefrontPlan(plan=plan, num_levels=num_levels,
@@ -281,8 +279,7 @@ def wavefront_eval(wplan: WavefrontPlan, x: jax.Array,
         z = level_grouped_matmul(combined, op_of_node, weights,
                                  num_ops=num_ops, plan=plan,
                                  schedule=segmm_schedule, path=segmm_path,
-                                 bm=bm, bn=bn, bk=bk,
-                                 interpret=plan.interpret)
+                                 bm=bm, bn=bn, bk=bk)
         if bias is not None:
             z = z + bias[op_of_node]
         # each output row depends only on its own combined row, so the

@@ -27,7 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
 
 AXIS = "pod"
 
@@ -74,11 +73,11 @@ def make_pipeline_apply(stage_fn: Callable, mesh: Mesh, num_stages: int,
         # keep only this stage's outputs; callers read the last stage's.
         return ys[None]  # [1, T, mb, ...] -> stacked over pods by out_spec
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         per_pod, mesh=mesh,
         in_specs=(P(AXIS), P()),        # stage params by pod; inputs repl.
         out_specs=P(AXIS),              # [P, T, mb, ...]
-        check=False)
+        check_vma=False)
 
     def apply(stage_params, xs):
         ys_all = sharded(stage_params, xs)                  # [P, T, mb, ...]

@@ -19,7 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import use_ambient_mesh
 from repro.configs.base import ModelConfig
 from repro.models import init_params, lm_loss
 from repro.train.optimizer import OptConfig, adamw_update
@@ -141,7 +140,7 @@ def make_train_step(cfg: ModelConfig, opt_cfg: OptConfig, mesh: Mesh, *,
     def step_fn(params, opt_state, batch):
         # the abstract mesh is active while this traces -> maybe_constrain
         # pins activation shardings against it.
-        with use_ambient_mesh(mesh):
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
             loss, metrics, grads = loss_and_grads(params, cfg, batch,
                                                   num_microbatches, dtype,
                                                   mesh=mesh)

@@ -41,17 +41,18 @@ def pytest_collection_modifyitems(config, items):
                 strict=False))
 
 
-@pytest.fixture(autouse=True, scope="module")
+@pytest.fixture(autouse=True, scope="class")
 def _bound_jax_compile_cache():
-    """Flush jax's in-process caches at each module boundary.
+    """Flush jax's in-process caches at each class (or module) boundary.
 
     A full tier-1 run compiles thousands of distinct programs into one
     process; past a few hundred, XLA:CPU's compiler can segfault on an
     otherwise-fine compile (observed deterministically at ~470 tests in —
-    the same test passes in isolation or any shorter prefix).  Clearing
-    between modules keeps the live compiled-program population bounded;
-    within a module, tests still share traces, so the re-trace cost is one
-    warmup per module, not per test.
+    the same test passes in isolation or any shorter prefix; one module,
+    ``test_graph_advance.py``, crossed that line alone).  Clearing between
+    test classes keeps the live compiled-program population bounded;
+    within a class, tests still share traces, so the re-trace cost is one
+    warmup per class, not per test.
     """
     yield
     import jax

@@ -145,6 +145,36 @@ class TestNativeTileReduce:
                                       np.asarray(tile_reduce(spec, fn)))
 
 
+class TestLaneTake:
+    """``lane_take`` is ``x[idx]``, lanes leading, however it is vmapped."""
+
+    x = jnp.arange(4 * 50, dtype=jnp.float32).reshape(4, 50) * 0.5
+    idx = jnp.asarray([[3, 0, 49], [7, 7, 1]], jnp.int32)
+
+    @pytest.mark.parametrize("batched", ["x", "idx", "both"])
+    def test_matches_indexing_under_vmap(self, batched):
+        from repro.core.execute import lane_take
+        x, idx = self.x, self.idx
+        if batched == "idx":
+            x, idx = x[0], jnp.stack([idx, idx[::-1], idx + 1, idx + 2])
+        elif batched == "both":
+            idx = jnp.stack([idx, idx[::-1], idx + 1, idx + 2])
+        axes = {"x": (0, None), "idx": (None, 0), "both": (0, 0)}[batched]
+        got = jax.jit(jax.vmap(lane_take, in_axes=axes))(x, idx)
+        want = jax.vmap(lambda a, i: a[i], in_axes=axes)(x, idx)
+        assert got.shape == want.shape
+        assert_bitwise_equal(got, want)
+
+    def test_nested_vmap_and_unbatched(self):
+        from repro.core.execute import lane_take
+        xs = jnp.stack([self.x, -self.x])                    # [2, 4, 50]
+        got = jax.vmap(jax.vmap(lane_take, in_axes=(0, None)),
+                       in_axes=(0, None))(xs, self.idx)
+        assert_bitwise_equal(got, xs[:, :, self.idx])
+        assert_bitwise_equal(lane_take(self.x[1], self.idx),
+                             self.x[1][self.idx])
+
+
 class TestInvertBlockMap:
     def test_round_trip(self):
         bm = jnp.asarray([2, 0, 1, 0, 2, 2], jnp.int32)

@@ -7,6 +7,7 @@ where retire/backfill boundaries fall — and that the whole stream is
 served with exactly ONE trace of the step and admit functions.
 """
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
@@ -22,6 +23,15 @@ def _graph(seed=0, V=20, density=0.18):
                  0.0).astype(np.float32)
     np.fill_diagonal(w, 0.0)
     return Graph(CSR.from_dense(w))
+
+
+def test_optimization_barrier_batches_under_vmap():
+    # the PageRank body pins its rounding with optimization_barrier; it
+    # must stay usable under vmap (jax's own batching rule, no patch)
+    x = jnp.arange(12.0, dtype=jnp.float32).reshape(3, 4)
+    out = jax.vmap(lambda r: jax.lax.optimization_barrier((r * 2, r + 1)))(x)
+    np.testing.assert_array_equal(out[0], np.asarray(x) * 2)
+    np.testing.assert_array_equal(out[1], np.asarray(x) + 1)
 
 
 def _driver_answer(g, plan, kind, source, direction="pull"):

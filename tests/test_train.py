@@ -10,6 +10,7 @@ from repro.configs import get_config
 from repro.models import init_params
 from repro.data.synthetic import DataConfig, batch_at, for_model
 from repro.data.packing import pack_documents, packing_efficiency
+from repro.launch.mesh import AxisType, make_mesh
 from repro.train import checkpoint as ckpt
 from repro.train.compress import (compress_roundtrip, ef_compress,
                                   init_error_state)
@@ -18,7 +19,8 @@ from repro.train.step import make_train_step, param_specs, shardings_for
 
 
 def tiny_mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"),
+                     axis_types=(AxisType.Auto, AxisType.Auto))
 
 
 class TestOptimizer:
@@ -138,7 +140,8 @@ class TestCheckpoint:
         the elastic-scaling path."""
         cfg, params, opt, d = self._setup(tmp_path)
         ckpt.save(d, 3, params, opt)
-        mesh2 = jax.make_mesh((1,), ("model",))  # different topology
+        mesh2 = make_mesh((1,), ("model",),  # different topology
+                          axis_types=(AxisType.Auto,))
         psh = shardings_for(mesh2, param_specs(cfg))
         p2, _, _ = ckpt.restore(d, 3, params, opt, param_sh=psh)
         for a, b in zip(jax.tree.leaves(params), jax.tree.leaves(p2)):
