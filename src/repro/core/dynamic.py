@@ -47,6 +47,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import telemetry
 from repro.core.balance import CHUNK_OVERHEAD, LANES
 from repro.core.schedules import (Partition, Schedule, finalize_partition,
                                   tile_mapped_partition)
@@ -201,7 +202,6 @@ def chunked_partition(spec: WorkSpec, num_blocks: int, *,
 _ADAPTIVE_CACHE: "OrderedDict[tuple, Partition]" = OrderedDict()
 _ADAPTIVE_CACHE_CAPACITY = 256
 _ADAPTIVE_CACHE_LOCK = threading.Lock()
-_INSPECTION_COUNT = 0
 
 
 def adaptive_inspection_count() -> int:
@@ -210,7 +210,7 @@ def adaptive_inspection_count() -> int:
     Monotonic process-wide counter for regression tests: repeated calls on
     the same workload must not re-inspect.
     """
-    return _INSPECTION_COUNT
+    return telemetry.counters().get("adaptive_inspections", 0)
 
 
 def clear_adaptive_cache() -> None:
@@ -239,7 +239,6 @@ def adaptive_partition(spec: WorkSpec, num_blocks: int, *,
     a serving loop can call this per request without paying the inspector
     each time.  ``cache=False`` forces a fresh inspection.
     """
-    global _INSPECTION_COUNT
     num_blocks = max(int(num_blocks), 1)
     key = None
     if cache:
@@ -251,7 +250,7 @@ def adaptive_partition(spec: WorkSpec, num_blocks: int, *,
                 if hit is not None:
                     _ADAPTIVE_CACHE.move_to_end(key)
                     return hit
-    _INSPECTION_COUNT += 1
+    telemetry.count("adaptive_inspections")
     part = _adaptive_partition_uncached(spec, num_blocks,
                                         imbalance_threshold)
     if key is not None:

@@ -55,7 +55,7 @@ AtomFn = Union[Callable[[jax.Array], jax.Array], jax.Array]
 WINDOW_ALIGN = 8 * 128
 
 
-def pallas_call(kernel, **kwargs):
+def pallas_call(kernel, *, name: str, **kwargs):
     """``pl.pallas_call`` that interprets exactly where it is lowered for
     the CPU.
 
@@ -64,15 +64,18 @@ def pallas_call(kernel, **kwargs):
     it.  The choice is made per lowering platform
     (``lax.platform_dependent``), not per process: the same traced program
     runs in the interpreter under the CPU tests and compiles natively when
-    lowered for a TPU, attached or described by a topology.
+    lowered for a TPU, attached or described by a topology.  Every launch
+    runs in the ``kernel`` scope, under the kernel's stable ``name``.
     """
     from jax.experimental import pallas as pl
 
     def call(*args):
-        return jax.lax.platform_dependent(
-            *args,
-            cpu=pl.pallas_call(kernel, interpret=True, **kwargs),
-            default=pl.pallas_call(kernel, **kwargs))
+        with jax.named_scope("kernel"):
+            return jax.lax.platform_dependent(
+                *args,
+                cpu=pl.pallas_call(kernel, interpret=True, name=name,
+                                   **kwargs),
+                default=pl.pallas_call(kernel, name=name, **kwargs))
     return call
 
 #: Reduction combiners usable by every executor.  ``sum`` is the paper's
@@ -95,6 +98,7 @@ def _check_combiner(combiner: str, dtype) -> float:
     return COMBINER_IDENTITY[combiner]
 
 
+@jax.named_scope("scatter")
 def _segment_reduce(combiner: str, values: jax.Array, segment_ids: jax.Array,
                     num_segments: int) -> jax.Array:
     """Segmented reduction under the named combiner (identity fill)."""
@@ -263,6 +267,7 @@ def _window_sizes(spec: WorkSpec, part: Partition) -> Tuple[int, int]:
     return window, local_tiles
 
 
+@jax.named_scope("fixup")
 def fixup_partials(spec: WorkSpec, part: Partition, partials: jax.Array,
                    local_tiles: int, combiner: str = "sum") -> jax.Array:
     """Scatter-combine per-chunk partials at their global tile offsets.
@@ -302,34 +307,37 @@ def blocked_tile_reduce(spec: WorkSpec, part: Partition, atom_fn: AtomFn,
     grid = part.num_blocks
     window, local_tiles = _window_sizes(spec, part)
 
-    atom_base = part.atom_starts[:-1]                       # [G]
-    idx = atom_base[:, None] + jnp.arange(window, dtype=jnp.int32)[None, :]
-    valid = idx < part.atom_starts[1:, None]                # [G, W]
-    safe_idx = jnp.clip(idx, 0, max(spec.num_atoms - 1, 0))
-    if atom_mask is not None:
-        valid = jnp.logical_and(valid, atom_mask[safe_idx])
+    with jax.named_scope("windows"):
+        atom_base = part.atom_starts[:-1]                   # [G]
+        idx = (atom_base[:, None]
+               + jnp.arange(window, dtype=jnp.int32)[None, :])
+        valid = idx < part.atom_starts[1:, None]            # [G, W]
+        safe_idx = jnp.clip(idx, 0, max(spec.num_atoms - 1, 0))
+        if atom_mask is not None:
+            valid = jnp.logical_and(valid, atom_mask[safe_idx])
 
-    values = lane_take(atom_values(atom_fn, spec.num_atoms, dtype), safe_idx)
-    values = jnp.where(valid, values, jnp.asarray(identity, dtype))
+        values = lane_take(atom_values(atom_fn, spec.num_atoms, dtype),
+                           safe_idx)
+        values = jnp.where(valid, values, jnp.asarray(identity, dtype))
 
-    tile_ids = spec.atom_tile_ids()                          # [A]
-    tids = tile_ids[safe_idx]                                # [G, W]
-    local = tids - part.tile_starts[:-1, None]               # [G, W]
-    local = jnp.where(valid, local, local_tiles)             # mask -> OOB bin
+        tile_ids = spec.atom_tile_ids()                      # [A]
+        tids = tile_ids[safe_idx]                            # [G, W]
+        local = tids - part.tile_starts[:-1, None]           # [G, W]
+        local = jnp.where(valid, local, local_tiles)         # mask -> OOB bin
 
-    onehot = (local[..., None]
-              == jnp.arange(local_tiles, dtype=jnp.int32)[None, None, :])
-    if combiner == "sum":
-        # One-hot contraction per block: [G, W] x [W, local_tiles] (MXU).
-        partials = jnp.einsum("gw,gwl->gl", values, onehot.astype(dtype))
-    else:
-        # min/max: masked elementwise reduce over the window — no dot
-        # product expresses these, but the window/bin shapes are identical
-        # to the sum path so the fixup stays shared.
-        contrib = jnp.where(onehot, values[..., None],
-                            jnp.asarray(identity, dtype))    # [G, W, L]
-        partials = (contrib.min(axis=1) if combiner == "min"
-                    else contrib.max(axis=1))
+        onehot = (local[..., None]
+                  == jnp.arange(local_tiles, dtype=jnp.int32)[None, None, :])
+        if combiner == "sum":
+            # One-hot contraction per block: [G, W] x [W, local_tiles] (MXU).
+            partials = jnp.einsum("gw,gwl->gl", values, onehot.astype(dtype))
+        else:
+            # min/max: masked elementwise reduce over the window — no dot
+            # product expresses these, but the window/bin shapes are
+            # identical to the sum path so the fixup stays shared.
+            contrib = jnp.where(onehot, values[..., None],
+                                jnp.asarray(identity, dtype))  # [G, W, L]
+            partials = (contrib.min(axis=1) if combiner == "min"
+                        else contrib.max(axis=1))
 
     return fixup_partials(spec, part, partials, local_tiles, combiner)
 
@@ -376,14 +384,15 @@ def native_chunk_tile_reduce(spec: WorkSpec, part: Partition, atom_fn: AtomFn,
 
     window, local_tiles = _window_sizes(spec, part)
     block_chunks, counts, _ = _chunk_queue_view(part)
-    values = _masked_values(atom_fn, spec.num_atoms, dtype, identity,
-                            atom_mask)
-    partials = chunk_walk_reduce(
-        values, spec.atom_tile_ids(), part.atom_starts.astype(jnp.int32),
-        part.tile_starts.astype(jnp.int32),
-        block_chunks.reshape(-1).astype(jnp.int32), counts.astype(jnp.int32),
-        window=window, local_tiles=local_tiles,
-        max_chunks=int(block_chunks.shape[1]), combiner=combiner)
+    with jax.named_scope("windows"):
+        values = _masked_values(atom_fn, spec.num_atoms, dtype, identity,
+                                atom_mask)
+        partials = chunk_walk_reduce(
+            values, spec.atom_tile_ids(), part.atom_starts.astype(jnp.int32),
+            part.tile_starts.astype(jnp.int32),
+            block_chunks.reshape(-1).astype(jnp.int32),
+            counts.astype(jnp.int32), window=window, local_tiles=local_tiles,
+            max_chunks=int(block_chunks.shape[1]), combiner=combiner)
     return fixup_partials(spec, part, partials, local_tiles, combiner)
 
 
@@ -432,6 +441,7 @@ def _window_slot_view(starts: jax.Array, slots: int
     return pos, in_chunk
 
 
+@jax.named_scope("windows")
 def blocked_value_windows(spec: WorkSpec, part: Partition, atom_fn: AtomFn,
                           dtype=jnp.float32, *, combiner: str = "sum",
                           atom_mask: jax.Array | None = None) -> jax.Array:
@@ -460,6 +470,7 @@ def blocked_value_windows(spec: WorkSpec, part: Partition, atom_fn: AtomFn,
     return jnp.where(valid, values, jnp.asarray(identity, dtype))
 
 
+@jax.named_scope("windows")
 def native_chunk_value_windows(spec: WorkSpec, part: Partition,
                                atom_fn: AtomFn, dtype=jnp.float32, *,
                                combiner: str = "sum",
@@ -525,6 +536,7 @@ def _values_by_position(starts: jax.Array, windows: jax.Array,
     return lane_take(windows.reshape(-1), slot)
 
 
+@jax.named_scope("scatter")
 def scatter_value_windows(spec: WorkSpec, part: Partition,
                           windows: jax.Array, out_ids: jax.Array,
                           num_out: int, combiner: str = "sum") -> jax.Array:
@@ -546,6 +558,7 @@ def scatter_value_windows(spec: WorkSpec, part: Partition,
 
 # -- gather-compacted active-atom windows (sparse-frontier push mode) -------
 
+@jax.named_scope("compact")
 def compact_active_atoms(atom_mask: jax.Array,
                          capacity: int) -> Tuple[jax.Array, jax.Array]:
     """Compact a bool atom mask into ``(idx [capacity], count)``.
@@ -603,6 +616,7 @@ def _compact_slots(part: Partition, idx: jax.Array) -> Tuple[int, int]:
                                                     int(idx.shape[0])))
 
 
+@jax.named_scope("windows")
 def blocked_compact_value_windows(spec: WorkSpec, part: Partition,
                                   atom_fn: AtomFn, idx: jax.Array,
                                   dtype=jnp.float32, *,
@@ -624,6 +638,7 @@ def blocked_compact_value_windows(spec: WorkSpec, part: Partition,
     return jnp.where(valid, values, jnp.asarray(identity, dtype))
 
 
+@jax.named_scope("windows")
 def native_compact_value_windows(spec: WorkSpec, part: Partition,
                                  atom_fn: AtomFn, idx: jax.Array,
                                  dtype=jnp.float32, *,
@@ -654,6 +669,7 @@ def native_compact_value_windows(spec: WorkSpec, part: Partition,
                            _compact_window(num_chunks, capacity), combiner)
 
 
+@jax.named_scope("scatter")
 def scatter_compact_windows(spec: WorkSpec, windows: jax.Array,
                             idx: jax.Array, out_ids: jax.Array,
                             num_out: int, combiner: str = "sum") -> jax.Array:
@@ -714,6 +730,7 @@ def execute_scatter_reduce(spec: WorkSpec, part: Partition, atom_fn: AtomFn,
                  and jnp.dtype(dtype) == jnp.dtype(jnp.float32))
     resolved = resolve_execution_path(path, native_supported=native_ok)
 
+    @jax.named_scope("masked")
     def masked(_=None):
         if resolved == ExecutionPath.NATIVE:
             windows = native_chunk_value_windows(spec, part, atom_fn, dtype,
@@ -731,6 +748,7 @@ def execute_scatter_reduce(spec: WorkSpec, part: Partition, atom_fn: AtomFn,
     capacity = int(min(max(int(compact_capacity), 1), spec.num_atoms))
     idx, count = compact_active_atoms(atom_mask, capacity)
 
+    @jax.named_scope("compact")
     def compact(_):
         if resolved == ExecutionPath.NATIVE:
             windows = native_compact_value_windows(spec, part, atom_fn, idx,

@@ -25,10 +25,11 @@ happily report a median dominated by compilation).
 Measurement counting
 --------------------
 
-Every ``time_fn`` call bumps a module-level counter,
-:func:`measurement_count` — the regression hook tests use to assert the
-autotuner's persisted measurements are *reused* on reload rather than
-re-taken (measuring is the expensive step the v2 cache exists to amortize).
+Every ``time_fn`` call bumps the ``measurements`` counter of
+:mod:`repro.core.telemetry`, which :func:`measurement_count` reads — the
+regression hook tests use to assert the autotuner's persisted measurements
+are *reused* on reload rather than re-taken (measuring is the expensive
+step the v2 cache exists to amortize).
 """
 from __future__ import annotations
 
@@ -38,12 +39,12 @@ from typing import Iterable
 
 import jax
 
-_measurement_count = 0
+from repro.core import telemetry
 
 
 def measurement_count() -> int:
     """Total ``time_fn`` invocations in this process (re-measurement hook)."""
-    return _measurement_count
+    return telemetry.counters().get("measurements", 0)
 
 
 def time_fn(fn, *args, warmup: int = 2, iters: int = 5) -> float:
@@ -61,8 +62,7 @@ def time_fn(fn, *args, warmup: int = 2, iters: int = 5) -> float:
             f"trace/compile, which must not pollute the steady-state median")
     if iters < 1:
         raise ValueError(f"time_fn needs iters >= 1 (got {iters})")
-    global _measurement_count
-    _measurement_count += 1
+    telemetry.count("measurements")
     for _ in range(warmup):
         out = fn(*args)
         jax.block_until_ready(out)

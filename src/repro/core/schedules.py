@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import telemetry
 from repro.core.work import WorkSpec
 
 
@@ -291,13 +292,6 @@ def merge_path_partition(spec: WorkSpec, num_blocks: int) -> Partition:
 # Registry / dispatch.
 # ---------------------------------------------------------------------------
 
-# Build counter for regression tests: ops that batch many computations over
-# one workload (spmm over B's columns, graph traversals over iterations)
-# must build their Partition once, not per column/iteration.  Counting at
-# the registry keeps the invariant checkable from the outside.
-_PARTITION_BUILD_COUNT = 0
-
-
 def partition_build_count() -> int:
     """Process-wide count of concrete partition builds via make_partition.
 
@@ -306,17 +300,20 @@ def partition_build_count() -> int:
     autotune cache therefore adds one count per scored schedule plus one
     for the winning build (a warm cache adds exactly one).  Regression
     tests should pin explicit schedules, where one call == one build.
+    Ops that batch many computations over one workload (spmm over B's
+    columns, graph traversals over iterations) must build their Partition
+    once, not per column/iteration; counting at the registry keeps that
+    checkable from the outside.
     """
-    return _PARTITION_BUILD_COUNT
+    return telemetry.counters().get("partition_builds", 0)
 
 
 def make_partition(spec: WorkSpec, schedule: Schedule | str,
                    num_blocks: int, *, chunk_policy: str = "lpt"
                    ) -> Partition:
-    global _PARTITION_BUILD_COUNT
     schedule = Schedule(schedule)
     if schedule != Schedule.AUTO:
-        _PARTITION_BUILD_COUNT += 1
+        telemetry.count("partition_builds")
     if schedule in (Schedule.THREAD_MAPPED,):
         return tile_mapped_partition(spec, num_blocks, schedule)
     if schedule in (Schedule.GROUP_MAPPED, Schedule.WARP_MAPPED,

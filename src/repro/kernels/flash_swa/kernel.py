@@ -96,6 +96,7 @@ def flash_swa(q: jax.Array, k: jax.Array, v: jax.Array, *, window: int,
     return pallas_call(
         functools.partial(_flash_swa_kernel, qc=qc, window=window, wb=wb,
                           scale=scale),
+        name="flash_swa",
         grid=(b, h, nq, wb + 1),
         in_specs=[
             pl.BlockSpec((1, qc, 1, hd), q_index),

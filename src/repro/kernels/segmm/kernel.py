@@ -66,6 +66,7 @@ def segmented_matmul(lhs_padded: jax.Array, rhs: jax.Array,
 
     return pallas_call(
         _segmm_kernel,
+        name="segmented_matmul",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,
@@ -151,6 +152,7 @@ def segmented_matmul_chunked(lhs_padded: jax.Array, rhs: jax.Array,
 
     return pallas_call(
         functools.partial(_segmm_chunk_kernel, bm=bm, max_chunks=max_chunks),
+        name="segmented_matmul_chunked",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=grid,

@@ -81,6 +81,7 @@ def spmv_merge_stream(stream_vals: jax.Array, stream_rows: jax.Array,
 
     partials = pallas_call(
         functools.partial(_spmv_block_kernel, r_loc=r_loc),
+        name="spmv_merge_stream",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(grid,),
@@ -230,6 +231,7 @@ def _launch(vals, tids, atom_starts, tile_starts, block_chunks_flat,
     out = pallas_call(
         functools.partial(_chunk_walk_kernel, max_chunks=max_chunks,
                           combiner=combiner, emit=emit, bin_rows=rows),
+        name="chunk_walk_reduce",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=(lanes, num_physical, max_chunks),

@@ -58,7 +58,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro.core import ExecutionPath, Schedule
+from repro.core import ExecutionPath, Schedule, telemetry
 from repro.core.execute import lane_take
 from repro.sparse.advance import (AdvancePlan, advance, advance_push,
                                   build_advance)
@@ -196,8 +196,8 @@ class GraphServer:
         self._next_qid = 0
         self.steps = 0            # serving steps executed
         self.served = 0           # queries retired
-        self._step_traces: List[float] = []   # appended at trace time
-        self._admit_traces: List[float] = []
+        self._step_traces = 0     # counted at trace time
+        self._admit_traces = 0
 
         self.batch = self._empty_batch()
         self._jstep = jax.jit(self._make_step())
@@ -228,7 +228,7 @@ class GraphServer:
         # The plan is the step's argument, not a closure: closed over by
         # jax.jit, its arrays would be compiled into the step as constants.
         def step(plan: AdvancePlan, b: QueryBatch) -> QueryBatch:
-            self._step_traces.append(time.perf_counter())
+            self._step_traces += 1
             W, V = b.value.shape
             outdeg = plan.out_degrees.astype(jnp.float32)
             src, psrc = plan.src, plan.push_src
@@ -348,7 +348,7 @@ class GraphServer:
             # lanes to (re)initialize from kind/source/qid.  Pure content
             # writes: the batch's shapes never change, so the serving step
             # never re-traces across retire/backfill boundaries.
-            self._admit_traces.append(time.perf_counter())
+            self._admit_traces += 1
             ids = jnp.arange(V, dtype=jnp.int32)
             is_pr = kind == KIND_PAGERANK
             f0 = jnp.logical_and(ids[None, :] == source[:, None],
@@ -386,12 +386,12 @@ class GraphServer:
     @property
     def step_traces(self) -> int:
         """Times the serving step has been traced (must stay 1)."""
-        return len(self._step_traces)
+        return self._step_traces
 
     @property
     def admit_traces(self) -> int:
         """Times the admit function has been traced (must stay 1)."""
-        return len(self._admit_traces)
+        return self._admit_traces
 
     # -- host-side serving loop -------------------------------------------
 
@@ -420,6 +420,7 @@ class GraphServer:
         self._queue.append(qid)
         return qid
 
+    @telemetry.span("serve.retire")
     def _retire(self) -> List[ServedResult]:
         """Read converged lanes off the device and free them (host side)."""
         occupied = self._lane_qid >= 0
@@ -453,6 +454,7 @@ class GraphServer:
         self._retired_lanes = done   # handed to the next admit as `clear`
         return results
 
+    @telemetry.span("serve.tick")
     def tick(self) -> List[ServedResult]:
         """One serving slot: retire converged lanes, backfill from the
         queue, advance every live lane one iteration.  Returns the queries
@@ -482,12 +484,14 @@ class GraphServer:
             self._lane_qid[lane] = q
 
         if clear.any() or take.any():
-            self.batch = self._jadmit(self.plan, self.batch,
-                                      jnp.asarray(clear),
-                                      jnp.asarray(take), jnp.asarray(kind),
-                                      jnp.asarray(source), jnp.asarray(qid))
+            with telemetry.span("serve.admit"):
+                self.batch = self._jadmit(
+                    self.plan, self.batch, jnp.asarray(clear),
+                    jnp.asarray(take), jnp.asarray(kind),
+                    jnp.asarray(source), jnp.asarray(qid))
         if (self._lane_qid >= 0).any():
-            self.batch = self._jstep(self.plan, self.batch)
+            with telemetry.span("serve.step"):
+                self.batch = self._jstep(self.plan, self.batch)
             self.steps += 1
         return results
 

@@ -65,7 +65,7 @@ from repro.core import (ExecutionPath, Partition, Schedule,
                         choose_execution_path, estimate_compact_capacity,
                         estimate_direction_threshold,
                         execute_scatter_reduce, execute_tile_reduce,
-                        make_partition)
+                        make_partition, telemetry)
 from repro.core.execute import AtomFn, lane_take
 from repro.core.work import WorkSpec
 
@@ -276,8 +276,9 @@ def _resolve_direction_plan(spec: WorkSpec, schedule, path, num_blocks: int,
     req_path = ExecutionPath(path)
     if sched == Schedule.AUTO:
         from repro.core.autotune import select_plan
-        plan = select_plan(spec, num_blocks, workload=workload,
-                           measure=measure)
+        with telemetry.span("inspect.autotune"):
+            plan = select_plan(spec, num_blocks, workload=workload,
+                               measure=measure)
         sched = plan.schedule
         policy = "lpt" if sched == Schedule.CHUNKED else None
         if req_path == ExecutionPath.AUTO:
@@ -337,6 +338,7 @@ _PUSH_WORKLOADS = {"advance": "advance_push",
                    "wavefront": "wavefront_push"}
 
 
+@telemetry.span("inspect")
 def build_advance(graph, *, schedule: Schedule | str = "auto",
                   num_blocks: Optional[int] = None,
                   path: ExecutionPath | str = ExecutionPath.AUTO,
@@ -413,6 +415,7 @@ def build_advance(graph, *, schedule: Schedule | str = "auto",
         pull_measure=pull_measure, push_measure=push_measure)
 
 
+@telemetry.span("inspect")
 def build_advance_views(*, pull_spec: WorkSpec, pull_src: jax.Array,
                         pull_weight: jax.Array, push_spec: WorkSpec,
                         push_dst: jax.Array, push_weight: jax.Array,
@@ -444,13 +447,15 @@ def build_advance_views(*, pull_spec: WorkSpec, pull_src: jax.Array,
     closures (or ``None``); everything else matches :func:`build_advance`.
     """
     num_blocks = DEFAULT_NUM_BLOCKS if num_blocks is None else num_blocks
-    sched, resolved, part = _resolve_direction_plan(
-        pull_spec, schedule, path, num_blocks, workload,
-        measure=pull_measure)
+    with telemetry.span("inspect.pull"):
+        sched, resolved, part = _resolve_direction_plan(
+            pull_spec, schedule, path, num_blocks, workload,
+            measure=pull_measure)
     push_workload = _PUSH_WORKLOADS.get(workload, workload)
-    push_sched, push_resolved, push_part = _resolve_direction_plan(
-        push_spec, schedule, path, num_blocks, push_workload,
-        measure=push_measure)
+    with telemetry.span("inspect.push"):
+        push_sched, push_resolved, push_part = _resolve_direction_plan(
+            push_spec, schedule, path, num_blocks, push_workload,
+            measure=push_measure)
     if direction_threshold is None:
         direction_threshold = estimate_direction_threshold(
             pull_spec, push_spec, num_blocks,
@@ -494,6 +499,7 @@ def build_advance_views(*, pull_spec: WorkSpec, pull_src: jax.Array,
     return plan
 
 
+@jax.named_scope("mask")
 def _combined_mask(vertex_mask: Optional[jax.Array], gather: jax.Array,
                    edge_mask: Optional[jax.Array]) -> Optional[jax.Array]:
     """frontier-gather AND edge-subset mask (either may be absent)."""

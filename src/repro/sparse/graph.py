@@ -43,7 +43,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import ExecutionPath, Schedule
+from repro.core import ExecutionPath, Schedule, telemetry
 from repro.core.execute import lane_take
 from repro.sparse.advance import (AdvancePlan, advance, advance_frontier,
                                   advance_push, advance_relax_min,
@@ -180,6 +180,7 @@ def _validate_sources(sources, num_vertices: int, *,
             f"(no valid sources)")
 
 
+@jax.named_scope("frontier")
 def _active_edge_count(plan: AdvancePlan, frontier: jax.Array) -> jax.Array:
     """Out-edges leaving the frontier — the measured-density carry term."""
     return jnp.sum(jnp.where(frontier, plan.out_degrees, 0)).astype(jnp.int32)
@@ -194,13 +195,15 @@ def _directed(plan: AdvancePlan, direction: str, active_edges: jax.Array,
     modeled threshold, so only the chosen branch executes at runtime.
     Returns ``(result, used_push)``.
     """
+    push = jax.named_scope("push")(push_fn)
+    pull = jax.named_scope("pull")(pull_fn)
     if direction == "push":
-        return push_fn(), jnp.bool_(True)
+        return push(), jnp.bool_(True)
     if direction == "pull":
-        return pull_fn(), jnp.bool_(False)
+        return pull(), jnp.bool_(False)
     density = plan.edge_fraction(active_edges)
     use_push = density < jnp.float32(plan.direction_threshold)
-    return (jax.lax.cond(use_push, lambda _: push_fn(), lambda _: pull_fn(),
+    return (jax.lax.cond(use_push, lambda _: push(), lambda _: pull(),
                          operand=None), use_push)
 
 
@@ -271,12 +274,16 @@ def sssp(graph: Graph, source: int, *, max_iters: Optional[int] = None,
         return _shard.sharded_sssp(
             splan, source, max_iters=max_iters, direction=direction,
             return_direction_counts=return_direction_counts)
-    V = graph.num_vertices
-    _validate_sources(source, V)
-    max_iters = V if max_iters is None else max_iters
-    aplan = _resolve_plan(graph, plan, schedule, num_blocks, path)
-    dist, counts = _sssp_loop(aplan, jnp.asarray(source, jnp.int32),
-                              max_iters=int(max_iters), direction=direction)
+    with telemetry.span("sssp"):
+        V = graph.num_vertices
+        _validate_sources(source, V)
+        max_iters = V if max_iters is None else max_iters
+        with telemetry.span("sssp.plan"):
+            aplan = _resolve_plan(graph, plan, schedule, num_blocks, path)
+        with telemetry.span("sssp.dispatch"):
+            dist, counts = _sssp_loop(aplan, jnp.asarray(source, jnp.int32),
+                                      max_iters=int(max_iters),
+                                      direction=direction)
     if return_direction_counts:
         return dist, counts
     return dist
@@ -298,6 +305,7 @@ def _sssp_loop(aplan: AdvancePlan, source: jax.Array, *, max_iters: int,
         i, _, frontier, _, _ = state
         return jnp.logical_and(i < max_iters, frontier.any())
 
+    @jax.named_scope("sssp.iter")
     def body(state):
         i, dist, frontier, active_edges, pushes = state
         new_dist, used_push = _relax_directed(aplan, direction, dist,
@@ -385,36 +393,40 @@ def delta_stepping(graph: Graph, source: int, *,
             splan, source, delta=delta, max_iters=max_iters,
             direction=direction,
             return_direction_counts=return_direction_counts)
-    V = graph.num_vertices
-    _validate_sources(source, V)
-    aplan = _resolve_plan(graph, plan, schedule, num_blocks, path,
-                          workload="advance_delta",
-                          delta=delta if delta is not None else "auto",
-                          compact=compact)
-    if aplan.delta is None or (delta is not None
-                               and float(delta) != aplan.delta):
-        aplan = aplan.with_delta(delta)
-    max_outer = (V + 2) if max_iters is None else max_iters
+    with telemetry.span("delta_stepping"):
+        V = graph.num_vertices
+        _validate_sources(source, V)
+        with telemetry.span("delta_stepping.plan"):
+            aplan = _resolve_plan(graph, plan, schedule, num_blocks, path,
+                                  workload="advance_delta",
+                                  delta=delta if delta is not None else "auto",
+                                  compact=compact)
+            if aplan.delta is None or (delta is not None
+                                       and float(delta) != aplan.delta):
+                aplan = aplan.with_delta(delta)
+        max_outer = (V + 2) if max_iters is None else max_iters
 
-    # Per-phase compaction capacity: a light-bucket advance can never
-    # activate more atoms than the light edge set holds (that count is the
-    # ceiling of the measured light density the carry tracks), so each
-    # phase's static capacity is clamped to its own edge subset and sparse
-    # bucket frontiers stream tighter gather-compacted windows.  The
-    # executor's measured-count ``lax.cond`` still arbitrates per advance,
-    # so a mis-sized capacity costs streamed volume, never bits.
-    light_cap = heavy_cap = aplan.compact_capacity
-    if aplan.compact_capacity is not None and aplan.num_edges:
-        # numpy on the plan's own (concrete, inspector-built) degree array:
-        # the whole driver may be wrapped in jax.jit, where a jnp.sum here
-        # would become a tracer and could not size a static capacity
-        light_edges = int(np.asarray(aplan.light_out_degrees).sum())
-        heavy_edges = aplan.num_edges - light_edges
-        light_cap = min(aplan.compact_capacity, max(light_edges, 1))
-        heavy_cap = min(aplan.compact_capacity, max(heavy_edges, 1))
-    dist, counts = _delta_loop(aplan, jnp.asarray(source, jnp.int32),
-                               max_outer=int(max_outer), direction=direction,
-                               light_cap=light_cap, heavy_cap=heavy_cap)
+        # Per-phase compaction capacity: a light-bucket advance can never
+        # activate more atoms than the light edge set holds (that count is the
+        # ceiling of the measured light density the carry tracks), so each
+        # phase's static capacity is clamped to its own edge subset and sparse
+        # bucket frontiers stream tighter gather-compacted windows.  The
+        # executor's measured-count ``lax.cond`` still arbitrates per advance,
+        # so a mis-sized capacity costs streamed volume, never bits.
+        light_cap = heavy_cap = aplan.compact_capacity
+        if aplan.compact_capacity is not None and aplan.num_edges:
+            # numpy on the plan's own (concrete, inspector-built) degree array:
+            # the whole driver may be wrapped in jax.jit, where a jnp.sum here
+            # would become a tracer and could not size a static capacity
+            light_edges = int(np.asarray(aplan.light_out_degrees).sum())
+            heavy_edges = aplan.num_edges - light_edges
+            light_cap = min(aplan.compact_capacity, max(light_edges, 1))
+            heavy_cap = min(aplan.compact_capacity, max(heavy_edges, 1))
+        with telemetry.span("delta_stepping.dispatch"):
+            dist, counts = _delta_loop(
+                aplan, jnp.asarray(source, jnp.int32),
+                max_outer=int(max_outer), direction=direction,
+                light_cap=light_cap, heavy_cap=heavy_cap)
     if return_direction_counts:
         return dist, counts
     return dist
@@ -448,6 +460,7 @@ def _delta_loop(aplan: AdvancePlan, source: jax.Array, *, max_outer: int,
         i, _, needs, _ = state
         return jnp.logical_and(i < max_outer, needs.any())
 
+    @jax.named_scope("delta.bucket")
     def outer_body(state):
         i, dist, needs, counts = state
         bucket = jnp.min(jnp.where(needs, _bucket_of(dist, width),
@@ -545,6 +558,7 @@ def _bfs_loop(aplan: AdvancePlan, source: jax.Array, max_iters: int,
     def cond(state):
         return jnp.logical_and(state[0] < max_iters, state[3].any())
 
+    @jax.named_scope("bfs.level")
     def body(state):
         # parent rides the carry only when requested (a dead [V] buffer
         # per vmap lane otherwise); slot 2 is a scalar placeholder then
@@ -566,7 +580,8 @@ def _bfs_loop(aplan: AdvancePlan, source: jax.Array, max_iters: int,
                 lambda: advance_frontier(aplan, frontier, direction="push"),
                 lambda: advance_frontier(aplan, frontier, direction="pull"))
             newly = jnp.logical_and(reached, depth < 0)
-        depth = jnp.where(newly, i + 1, depth)
+        with jax.named_scope("frontier"):
+            depth = jnp.where(newly, i + 1, depth)
         return (i + 1, depth, parent, newly,
                 _active_edge_count(aplan, newly),
                 pushes + used_push.astype(jnp.int32))
@@ -621,14 +636,16 @@ def bfs(graph: Graph, source: int, *, max_iters: Optional[int] = None,
             splan, source, max_iters=max_iters,
             return_parents=return_parents, direction=direction,
             return_direction_counts=return_direction_counts)
-    V = graph.num_vertices
-    _validate_sources(source, V)
-    max_iters = V if max_iters is None else max_iters
-    aplan = _resolve_plan(graph, plan, schedule, num_blocks, path)
-
-    depth, parent, counts = _bfs_loop(aplan, jnp.asarray(source, jnp.int32),
-                                      int(max_iters), direction,
-                                      return_parents)
+    with telemetry.span("bfs"):
+        V = graph.num_vertices
+        _validate_sources(source, V)
+        max_iters = V if max_iters is None else max_iters
+        with telemetry.span("bfs.plan"):
+            aplan = _resolve_plan(graph, plan, schedule, num_blocks, path)
+        with telemetry.span("bfs.dispatch"):
+            depth, parent, counts = _bfs_loop(
+                aplan, jnp.asarray(source, jnp.int32), int(max_iters),
+                direction, return_parents)
     out = (depth,)
     if return_parents:
         out = out + (parent,)
@@ -749,13 +766,17 @@ def pagerank(graph: Graph, *, damping: float = 0.85, num_iters: int = 50,
     V = graph.num_vertices
     if V == 0:
         return jnp.zeros((0,), jnp.float32)
-    # full-frontier sum-advance: no mask load/select per atom, so "auto"
-    # scores the plain "reduce" cost family, not the masked-advance one
-    aplan = _resolve_plan(graph, plan, schedule, num_blocks, path,
-                          workload="reduce")
-    return _pagerank_loop(aplan, graph.out_degrees().astype(jnp.float32),
-                          damping=float(damping), num_iters=int(num_iters),
-                          tol=float(tol), direction=direction)
+    with telemetry.span("pagerank"):
+        # full-frontier sum-advance: no mask load/select per atom, so "auto"
+        # scores the plain "reduce" cost family, not the masked-advance one
+        with telemetry.span("pagerank.plan"):
+            aplan = _resolve_plan(graph, plan, schedule, num_blocks, path,
+                                  workload="reduce")
+        outdeg = graph.out_degrees().astype(jnp.float32)
+        with telemetry.span("pagerank.dispatch"):
+            return _pagerank_loop(aplan, outdeg, damping=float(damping),
+                                  num_iters=int(num_iters), tol=float(tol),
+                                  direction=direction)
 
 
 # The loop runs under jit, not eagerly: XLA lowers the sum-advance's
@@ -775,17 +796,19 @@ def _pagerank_loop(aplan: AdvancePlan, outdeg: jax.Array, *, damping: float,
         i, _, delta = state
         return jnp.logical_and(i < num_iters, delta > tol)
 
+    @jax.named_scope("pagerank.iter")
     def body(state):
         i, pr, _ = state
         share = _pagerank_share(pr, outdeg)
+        with jax.named_scope("gather"):
+            shares = lane_take(share, src)
         if direction == "push":
-            contrib = advance_push(aplan, None, lane_take(share, src),
-                                   combiner="sum")
+            contrib = advance_push(aplan, None, shares, combiner="sum")
         else:
-            contrib = advance(aplan, None, lane_take(share, src),
-                              combiner="sum")
-        dangling = jnp.sum(jnp.where(outdeg > 0, 0.0, pr))
-        new_pr = _pagerank_update(contrib, dangling, damping, V)
+            contrib = advance(aplan, None, shares, combiner="sum")
+        with jax.named_scope("update"):
+            dangling = jnp.sum(jnp.where(outdeg > 0, 0.0, pr))
+            new_pr = _pagerank_update(contrib, dangling, damping, V)
         return i + 1, new_pr, jnp.abs(new_pr - pr).sum()
 
     pr0 = jnp.full((V,), 1.0 / V, jnp.float32)
