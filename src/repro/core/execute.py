@@ -352,9 +352,13 @@ def _chunk_queue_view(part: Partition) -> Tuple[jax.Array, jax.Array, int]:
         chunks, counts = invert_block_map(part.block_map, phys)
         return chunks, counts, int(counts.shape[0])
     # static schedule: every block is its own single-chunk queue
-    n = part.num_blocks
-    return (jnp.arange(n, dtype=jnp.int32)[:, None],
-            jnp.ones((n,), jnp.int32), n)
+    return _single_chunk_queues(part.num_blocks) + (part.num_blocks,)
+
+
+def _single_chunk_queues(num_chunks: int) -> Tuple[jax.Array, jax.Array]:
+    """(block_chunks, counts) with chunk ``c`` alone in queue ``c``."""
+    return (jnp.arange(num_chunks, dtype=jnp.int32)[:, None],
+            jnp.ones((num_chunks,), jnp.int32))
 
 
 def native_chunk_tile_reduce(spec: WorkSpec, part: Partition, atom_fn: AtomFn,
@@ -497,16 +501,18 @@ def native_chunk_value_windows(spec: WorkSpec, part: Partition,
                         dtype)
     values = _masked_values(atom_fn, spec.num_atoms, dtype, identity,
                             atom_mask)
-    return _native_windows(part, values, part.atom_starts, window, combiner)
+    return _native_windows(values, part.atom_starts, window, combiner,
+                           _chunk_queue_view(part)[:2])
 
 
-def _native_windows(part: Partition, values: jax.Array, starts: jax.Array,
-                    window: int, combiner: str) -> jax.Array:
+def _native_windows(values: jax.Array, starts: jax.Array, window: int,
+                    combiner: str, queues: Tuple[jax.Array, jax.Array]
+                    ) -> jax.Array:
     """``emit="atoms"`` kernel windows of ``values`` over chunk ``starts``,
-    popped in ``part``'s queue order."""
+    popped in the order of ``queues`` (``(block_chunks, counts)``)."""
     from repro.kernels.spmv_merge.kernel import chunk_walk_reduce
 
-    block_chunks, counts, _ = _chunk_queue_view(part)
+    block_chunks, counts = queues
     starts = starts.astype(jnp.int32)
     return chunk_walk_reduce(
         values, None, starts, jnp.zeros_like(starts),
@@ -567,12 +573,54 @@ def compact_active_atoms(atom_mask: jax.Array,
     ``num_atoms`` past the true count (so padded slots are recognisably out
     of range); ``count`` is the exact active-atom total, which callers
     compare against ``capacity`` to decide whether the compacted view is
-    complete (``jnp.nonzero(size=...)`` silently truncates past it).
-    Jit-safe: ``size=`` makes the nonzero shape static.
+    complete (past ``capacity`` the list is truncated, as
+    ``jnp.nonzero(size=...)`` truncates).  One prefix sum over the mask
+    gives each active atom its position and one scatter writes the list:
+    no pass over ``capacity`` slots but the fill.
     """
     num_atoms = int(atom_mask.shape[0])
-    (idx,) = jnp.nonzero(atom_mask, size=capacity, fill_value=num_atoms)
-    return idx.astype(jnp.int32), jnp.sum(atom_mask.astype(jnp.int32))
+    position = jnp.cumsum(atom_mask, dtype=jnp.int32) - 1
+    idx = jnp.full((capacity,), num_atoms, jnp.int32).at[
+        jnp.where(atom_mask, position, capacity)].set(
+            jnp.arange(num_atoms, dtype=jnp.int32), mode="drop")
+    return idx, jnp.sum(atom_mask, dtype=jnp.int32)
+
+
+def compact_rungs(capacity: int) -> Tuple[int, ...]:
+    """The ladder of static compaction capacities, top rung first.
+
+    The top rung is ``capacity``; each rung below holds half the one above
+    (rounded up), down to the first at or below :data:`WINDOW_ALIGN`.  A
+    traversal level runs the smallest rung that holds its active count
+    (:func:`compact_rung_index`), so a sparse frontier pays for windows
+    sized to itself, not to the densest push level the plan allows.
+    """
+    rungs = [max(int(capacity), 1)]
+    while rungs[-1] > WINDOW_ALIGN:
+        rungs.append(-(-rungs[-1] // 2))
+    return tuple(rungs)
+
+
+@jax.custom_batching.custom_vmap
+def compact_rung_index(count: jax.Array, rungs: jax.Array) -> jax.Array:
+    """Branch of the smallest rung of ``rungs`` (descending) that holds
+    ``count`` active atoms; ``len(rungs)``, the masked fallback, when none
+    does.
+
+    Under ``jax.vmap`` the index stays unbatched: it is taken for the
+    largest count over the lanes, a rung that holds every lane's atoms.  A
+    batched index would turn the ``lax.switch`` it drives into a select
+    that runs every rung for every lane.
+    """
+    holds = jnp.sum(count <= rungs, dtype=jnp.int32)
+    return jnp.where(holds > 0, holds - 1, rungs.shape[0])
+
+
+@compact_rung_index.def_vmap
+def _compact_rung_index_vmap(axis_size, in_batched, count, rungs):
+    if in_batched[0]:
+        count = jnp.max(count, axis=0)
+    return compact_rung_index(count, rungs), False
 
 
 def compact_chunk_starts(num_chunks: int, capacity: int) -> jax.Array:
@@ -580,8 +628,7 @@ def compact_chunk_starts(num_chunks: int, capacity: int) -> jax.Array:
 
     Compacted atoms are interchangeable units of equal cost, so the even
     split *is* the balanced partition — frontier skew was flattened by the
-    gather.  The chunk count mirrors the partition's own so the dynamic
-    schedules' queue discipline (``block_chunks``) applies unchanged.
+    gather.
     """
     per = _compact_window(num_chunks, capacity)
     return jnp.minimum(jnp.arange(num_chunks + 1, dtype=jnp.int32) * per,
@@ -610,10 +657,16 @@ def _compact_slot_view(spec: WorkSpec, idx: jax.Array, num_chunks: int,
 
 
 def _compact_slots(part: Partition, idx: jax.Array) -> Tuple[int, int]:
-    """(num_chunks, slots) of the compacted windows over ``part``."""
-    num_chunks = int(part.atom_starts.shape[0]) - 1
-    return num_chunks, window_slots(_compact_window(num_chunks,
-                                                    int(idx.shape[0])))
+    """(num_chunks, slots) of the compacted windows of ``idx`` over ``part``.
+
+    One chunk per :data:`WINDOW_ALIGN` compacted slots, at most the
+    partition's own chunk count: a small rung walks few chunks instead of
+    spreading a handful of atoms over every chunk of the partition.
+    """
+    capacity = int(idx.shape[0])
+    num_chunks = min(int(part.atom_starts.shape[0]) - 1,
+                     -(-capacity // WINDOW_ALIGN))
+    return num_chunks, window_slots(_compact_window(num_chunks, capacity))
 
 
 @jax.named_scope("windows")
@@ -625,11 +678,11 @@ def blocked_compact_value_windows(spec: WorkSpec, part: Partition,
 
     The sparse-frontier sibling of :func:`blocked_value_windows`: the
     windows walk even chunk splits of the compacted positions
-    (:func:`compact_chunk_starts`), and the slot at position ``k`` holds the
-    value of atom ``idx[k]`` — only active atoms occupy slots, so the
-    streamed window volume is the capacity, not the edge count.  Padded
-    index slots (``idx`` carries ``num_atoms`` past the true active count)
-    come back as the combiner's identity.
+    (:func:`compact_chunk_starts`, :func:`_compact_slots`), and the slot at
+    position ``k`` holds the value of atom ``idx[k]`` — only active atoms
+    occupy slots, so the streamed window volume is the capacity, not the
+    edge count.  Padded index slots (``idx`` carries ``num_atoms`` past the
+    true active count) come back as the combiner's identity.
     """
     identity = _check_combiner(combiner, dtype)
     num_chunks, slots = _compact_slots(part, idx)
@@ -647,8 +700,9 @@ def native_compact_value_windows(spec: WorkSpec, part: Partition,
 
     The gather through the compacted index list runs in XLA before the
     launch (Mosaic has no in-kernel 1-D gather); the kernel then walks even
-    chunk splits of the gathered values in its ``emit="atoms"`` mode, in
-    the partition's queue order — streaming only active atoms.  Chunk
+    chunk splits of the gathered values in its ``emit="atoms"`` mode —
+    streaming only active atoms.  Compacted chunks all cost the same, so
+    no queue discipline is needed: each grid step pops one chunk.  Chunk
     boundaries and layout equal the pure path's, so both paths produce
     identical windows and share one :func:`scatter_compact_windows` call.
     """
@@ -664,9 +718,10 @@ def native_compact_value_windows(spec: WorkSpec, part: Partition,
     values = lane_take(atom_values(atom_fn, spec.num_atoms, dtype),
                        jnp.clip(idx, 0, max(spec.num_atoms - 1, 0)))
     values = jnp.where(active, values, jnp.asarray(identity, dtype))
-    return _native_windows(part, values,
+    return _native_windows(values,
                            compact_chunk_starts(num_chunks, capacity),
-                           _compact_window(num_chunks, capacity), combiner)
+                           _compact_window(num_chunks, capacity), combiner,
+                           _single_chunk_queues(num_chunks))
 
 
 @jax.named_scope("scatter")
@@ -678,14 +733,19 @@ def scatter_compact_windows(spec: WorkSpec, windows: jax.Array,
     The compact-mode sibling of :func:`scatter_value_windows`: the value at
     compacted position ``k`` belongs to atom ``idx[k]``, whose output
     segment is that atom's ``out_ids`` entry; padded positions are dropped.
+    Position ``k`` sits in chunk ``k // per`` of the even split, at slot
+    ``k - origin`` of its row, so it is read back without a prefix sum.
     Active atoms keep their ascending order, so for the exact combiners —
     and exactly-summable values — results are bit-identical to the masked
     full-window scatter.
     """
+    num_chunks, slots = int(windows.shape[0]), int(windows.shape[1])
     capacity = int(idx.shape[0])
-    values = _values_by_position(
-        compact_chunk_starts(int(windows.shape[0]), capacity), windows,
-        capacity)
+    per = _compact_window(num_chunks, capacity)
+    k = jnp.arange(capacity, dtype=jnp.int32)
+    chunk = k // per
+    slot = chunk * slots + k - (chunk * per // WINDOW_ALIGN) * WINDOW_ALIGN
+    values = lane_take(windows.reshape(-1), slot)
     gid = jnp.where(idx < spec.num_atoms,
                     out_ids[jnp.clip(idx, 0, max(spec.num_atoms - 1, 0))],
                     num_out)
@@ -713,13 +773,19 @@ def execute_scatter_reduce(spec: WorkSpec, part: Partition, atom_fn: AtomFn,
     tile-reduce over the same edge multiset.
 
     ``compact_capacity`` (static int, requires ``atom_mask``) enables the
-    gather-compacted window mode: the active atoms are compacted into a
-    ``capacity``-slot index list and only those slots are streamed — the
-    ROADMAP's frontier compaction.  When the runtime active count exceeds
-    the capacity, a ``lax.cond`` falls back to the masked full-window mode,
-    so any capacity is *correct*; a well-chosen one (see
+    gather-compacted window mode: the active atoms are compacted into an
+    index list and only its slots are streamed — the ROADMAP's frontier
+    compaction.  The windows' static size is a rung of
+    :func:`compact_rungs`: the capacity, and halvings of it down to one
+    window tile.  The exact active count picks the smallest rung that
+    holds it (:func:`compact_rung_index`, unbatched under ``vmap``); the
+    mask's prefix sum and the index list are built once, and a
+    ``lax.switch`` runs that rung on the list's head under the scope
+    ``compact.r<k>`` (``k = 0`` for the top rung).  When the count exceeds
+    the capacity, a ``lax.cond`` falls back to the masked full-window mode
+    instead, so any capacity is *correct*; a well-chosen one (see
     :func:`repro.core.balance.estimate_compact_capacity`) is merely fast.
-    Both modes share the segmented scatter in ascending atom order, so
+    Every mode shares the segmented scatter in ascending atom order, so
     results stay bit-identical for exact combiners and exactly-summable
     values.
     """
@@ -731,7 +797,7 @@ def execute_scatter_reduce(spec: WorkSpec, part: Partition, atom_fn: AtomFn,
     resolved = resolve_execution_path(path, native_supported=native_ok)
 
     @jax.named_scope("masked")
-    def masked(_=None):
+    def masked():
         if resolved == ExecutionPath.NATIVE:
             windows = native_chunk_value_windows(spec, part, atom_fn, dtype,
                                                  combiner=combiner,
@@ -745,21 +811,31 @@ def execute_scatter_reduce(spec: WorkSpec, part: Partition, atom_fn: AtomFn,
 
     if compact_capacity is None or atom_mask is None:
         return masked()
-    capacity = int(min(max(int(compact_capacity), 1), spec.num_atoms))
-    idx, count = compact_active_atoms(atom_mask, capacity)
+    rungs = compact_rungs(min(int(compact_capacity), spec.num_atoms))
+    index = compact_rung_index(jnp.sum(atom_mask, dtype=jnp.int32),
+                               jnp.asarray(rungs, jnp.int32))
+
+    def rung(idx: jax.Array, k: int):
+        @jax.named_scope(f"compact.r{k}")
+        def run():
+            head = idx[:rungs[k]]
+            if resolved == ExecutionPath.NATIVE:
+                windows = native_compact_value_windows(
+                    spec, part, atom_fn, head, dtype, combiner=combiner)
+            else:
+                windows = blocked_compact_value_windows(
+                    spec, part, atom_fn, head, dtype, combiner=combiner)
+            return scatter_compact_windows(spec, windows, head, out_ids,
+                                           num_out, combiner)
+        return run
 
     @jax.named_scope("compact")
-    def compact(_):
-        if resolved == ExecutionPath.NATIVE:
-            windows = native_compact_value_windows(spec, part, atom_fn, idx,
-                                                   dtype, combiner=combiner)
-        else:
-            windows = blocked_compact_value_windows(spec, part, atom_fn, idx,
-                                                    dtype, combiner=combiner)
-        return scatter_compact_windows(spec, windows, idx, out_ids, num_out,
-                                       combiner)
+    def compact():
+        idx, _ = compact_active_atoms(atom_mask, rungs[0])
+        return jax.lax.switch(index, [rung(idx, k)
+                                      for k in range(len(rungs))])
 
-    return jax.lax.cond(count <= capacity, compact, masked, operand=None)
+    return jax.lax.cond(index < len(rungs), compact, masked)
 
 
 def execute_tile_reduce(spec: WorkSpec, part: Partition, atom_fn: AtomFn,

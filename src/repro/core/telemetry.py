@@ -46,6 +46,9 @@ SCOPES = (
     "pull",           # the pull branch
     "mask",           # frontier gather AND edge-subset mask per edge
     "compact",        # gather-compaction of the active edges, and its branch
+    # the compacted branch's rung k (core/execute.py::compact_rungs), r0
+    # the top; 22 rungs cover every capacity below 2**31
+    *(f"compact.r{k}" for k in range(22)),
     "masked",         # the full-window fallback of a compacting advance
     "windows",        # per-chunk value windows (pure or kernel)
     "scatter",        # segmented reductions by output id

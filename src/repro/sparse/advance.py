@@ -45,7 +45,9 @@ Two refinements ride the same plan pair (this PR):
   needs — its bucket loops are ordinary advances restricted by
   ``edges="light"``/``"heavy"``; and
 * **frontier compaction** (``build_advance(..., compact=)``): the push
-  direction's masked windows are gather-compacted to a static capacity
+  direction's masked windows are gather-compacted to a static capacity,
+  and each advance runs the smallest rung of a ladder of halvings of it
+  that holds its active edges
   (:func:`repro.core.execute.execute_scatter_reduce`), so sparse frontiers
   stream only their own out-edges — with a masked fallback past capacity,
   results never change, only streamed volume.
@@ -554,8 +556,9 @@ def advance_push(plan: AdvancePlan, frontier: Optional[jax.Array],
     the same bits as the pull advance over the same edge multiset.
 
     On a plan built with ``compact=...`` the masked atoms are additionally
-    *gather-compacted* before streaming (``compact_capacity`` slots, with
-    an in-executor masked fallback past capacity) — sparse frontiers stream
+    *gather-compacted* before streaming (into the smallest rung of the
+    ladder below ``compact_capacity`` slots that holds them, with an
+    in-executor masked fallback past capacity) — sparse frontiers stream
     only their own out-edges instead of masking full windows, without
     changing a single result bit.
     """

@@ -411,8 +411,9 @@ def delta_stepping(graph: Graph, source: int, *,
         # ceiling of the measured light density the carry tracks), so each
         # phase's static capacity is clamped to its own edge subset and sparse
         # bucket frontiers stream tighter gather-compacted windows.  The
-        # executor's measured-count ``lax.cond`` still arbitrates per advance,
-        # so a mis-sized capacity costs streamed volume, never bits.
+        # executor's measured count still picks each advance's rung below
+        # that capacity, or the masked fallback above it, so a mis-sized
+        # capacity costs streamed volume, never bits.
         light_cap = heavy_cap = aplan.compact_capacity
         if aplan.compact_capacity is not None and aplan.num_edges:
             # numpy on the plan's own (concrete, inspector-built) degree array:

@@ -15,8 +15,11 @@ import numpy as np
 import jax.numpy as jnp
 import pytest
 
+import jax
+
 from repro.core import (ExecutionPath, Plan, Schedule,
                         blocked_compact_value_windows, compact_active_atoms,
+                        compact_rung_index, compact_rungs,
                         estimate_compact_capacity,
                         estimate_direction_threshold, execute_scatter_reduce,
                         make_partition, modeled_advance_cost,
@@ -34,6 +37,11 @@ from _conformance import (
 
 GRAPHS = {"powerlaw": powerlaw_graph_dense(40, avg_degree=5.0, seed=2),
           **adversarial_graphs(seed=3)}
+
+#: A skewed graph large enough for a ladder of compaction rungs: 8,463
+#: edges, and BFS levels from vertex 0 with 1, 9, 19, 58, 286, 499, 2,658
+#: and 4,933 active out-edges.
+LADDER_W = powerlaw_graph_dense(1500, avg_degree=6.0, seed=4)
 
 
 def graph_of(w) -> Graph:
@@ -658,6 +666,110 @@ class TestCompactWindows:
                                               idx)
         assert pure.shape == native.shape
         assert_bitwise_equal(pure.reshape(-1), native.reshape(-1))
+
+    # rungs (4096, 2048, 1024): every rung's edge, zero, and the fallback
+    LADDER_COUNTS = ((0, 2), (1, 2), (1024, 2), (1025, 1), (2048, 1),
+                     (2049, 0), (4096, 0), (4097, 3))
+
+    @pytest.mark.parametrize("combiner", ["sum", "min", "max"])
+    @pytest.mark.parametrize("path", PATHS, ids=str)
+    def test_compact_rungs_match_masked(self, path, combiner):
+        # each active count runs the smallest rung that holds it, and every
+        # rung (and the fallback past the top) returns the masked bits;
+        # integer values make the sums exact, so all three are bitwise
+        g = graph_of(LADDER_W)
+        spec = g.csr.workspec()
+        part = make_partition(spec, Schedule.CHUNKED, 8)
+        rungs = compact_rungs(4096)
+        assert rungs == (4096, 2048, 1024)
+        rng = np.random.default_rng(17)
+        vals = jnp.asarray(rng.integers(-8, 9, spec.num_atoms)
+                           .astype(np.float32))
+
+        def run(mask, capacity):
+            return execute_scatter_reduce(
+                spec, part, vals, g.csr.col_indices, g.num_vertices,
+                path=path, combiner=combiner, atom_mask=mask,
+                compact_capacity=capacity)
+
+        masked = jax.jit(lambda m: run(m, None))
+        compact = jax.jit(lambda m: run(m, rungs[0]))
+        order = rng.permutation(spec.num_atoms)
+        for count, want_rung in self.LADDER_COUNTS:
+            mask = np.zeros(spec.num_atoms, bool)
+            mask[order[:count]] = True
+            assert int(compact_rung_index(jnp.int32(count),
+                                          jnp.asarray(rungs))) == want_rung
+            assert_bitwise_equal(compact(jnp.asarray(mask)),
+                                 masked(jnp.asarray(mask)),
+                                 f"{path}/{combiner}/{count}")
+
+    def test_compact_rungs_halve_down_to_one_window_tile(self):
+        assert compact_rungs(1) == (1,)
+        assert compact_rungs(1024) == (1024,)
+        assert compact_rungs(1025) == (1025, 513)
+        rungs = compact_rungs(14719535)          # kron-s20's capacity
+        assert rungs[0] == 14719535 and rungs[-1] == 899
+        assert all(b == -(-a // 2) for a, b in zip(rungs, rungs[1:]))
+        assert all(r > 1024 for r in rungs[:-1])
+
+    @pytest.mark.parametrize("algo,path", [("bfs", "pure"), ("bfs", "native"),
+                                           ("sssp", "pure"),
+                                           ("delta", "pure")])
+    def test_compact_ladder_traversals_match_masked(self, algo, path):
+        # push levels of a skewed graph land on three rungs of
+        # (5400, 2700, 1350, 675); results equal the uncompacted plan's
+        g = graph_of(LADDER_W)
+        plans = {cap: build_advance(g, schedule="chunked", num_blocks=8,
+                                    path=path, compact=cap,
+                                    delta="auto" if algo == "delta" else None)
+                 for cap in (5400, None)}
+        run = {"bfs": lambda p: bfs(g, 0, plan=p, direction="push"),
+               "sssp": lambda p: sssp(g, 0, plan=p, direction="push"),
+               "delta": lambda p: delta_stepping(g, 0, plan=p,
+                                                 direction="push")}[algo]
+        got, want = run(plans[5400]), run(plans[None])
+        assert_bitwise_equal(got, want, f"{algo}/{path}")
+        if algo == "bfs":
+            depth = np.asarray(got)
+            np.testing.assert_array_equal(depth, np_bfs(LADDER_W, 0)[0])
+            out_deg = (LADDER_W > 0).sum(axis=1)
+            active = [int(out_deg[depth == d].sum())
+                      for d in range(depth.max() + 1)]
+            rungs = jnp.asarray(compact_rungs(5400))
+            used = {int(compact_rung_index(jnp.int32(a), rungs))
+                    for a in active}
+            assert used == {0, 1, 3}, (active, used)
+
+    def test_bfs_multi_traces_one_unbatched_rung_switch(self):
+        g = graph_of(LADDER_W)
+        plan = build_advance(g, schedule="chunked", num_blocks=8,
+                             path="pure", compact=5400)
+        sources = jnp.asarray([0, 7, 42])
+        run = lambda s: bfs_multi(g, s, plan=plan, direction="push")
+
+        def switches(jaxpr):
+            for eqn in jaxpr.eqns:
+                if eqn.primitive.name == "cond":
+                    yield eqn
+                for param in eqn.params.values():
+                    for sub in (param if isinstance(param, (list, tuple))
+                                else [param]):
+                        sub = getattr(sub, "jaxpr", sub)
+                        if hasattr(sub, "eqns"):
+                            yield from switches(sub)
+
+        rung_switches = [e for e in switches(jax.make_jaxpr(run)(sources)
+                                             .jaxpr)
+                         if len(e.params["branches"])
+                         == len(compact_rungs(5400))]
+        assert len(rung_switches) == 1
+        assert rung_switches[0].invars[0].aval.shape == ()
+        got = np.asarray(run(sources))
+        for lane, s in enumerate(np.asarray(sources)):
+            np.testing.assert_array_equal(
+                got[lane], np.asarray(bfs(g, int(s), plan=plan,
+                                          direction="push")))
 
     def test_compact_advance_push_rides_the_plan(self):
         # a plan built with compact= must keep push advances bit-identical

@@ -190,6 +190,41 @@ class TestDeltaSteppingEquivalence:
         assert_bitwise_equal(ds, bf, str(schedule))
 
 
+class TestCompactLadderEquivalence:
+    """The gather-compacted push reduce runs the smallest rung of its
+    capacity ladder that holds the frontier's active edges; whatever the
+    capacity, the frontier and the combiner, it returns the masked
+    reduce's bits."""
+
+    @pytest.mark.parametrize("path", ["pure", "native"])
+    @given(params=st.tuples(st.integers(min_value=40, max_value=72),
+                            st.floats(min_value=0.2, max_value=0.6),
+                            st.integers(min_value=0, max_value=2**31 - 1)),
+           frac=st.floats(min_value=0.0, max_value=1.0),
+           cap_frac=st.floats(min_value=0.0, max_value=1.1))
+    @settings(max_examples=4, deadline=None)
+    def test_any_capacity_matches_masked(self, path, params, frac,
+                                         cap_frac):
+        from repro.core import execute_scatter_reduce, make_partition
+        V, density, seed = params
+        w = random_digraph(V, density, seed)
+        g = Graph(CSR.from_dense(w))
+        spec = g.csr.workspec()
+        part = make_partition(spec, Schedule.CHUNKED, 5)
+        rng = np.random.default_rng(seed)
+        vals = jnp.asarray(rng.integers(-8, 9, spec.num_atoms)
+                           .astype(np.float32))
+        mask = jnp.asarray(rng.random(spec.num_atoms) < frac)
+        capacity = max(int(cap_frac * spec.num_atoms), 1)
+        for combiner in ("sum", "min", "max"):
+            got, want = (execute_scatter_reduce(
+                spec, part, vals, g.csr.col_indices, V, path=path,
+                combiner=combiner, atom_mask=mask, compact_capacity=cap)
+                for cap in (capacity, None))
+            assert_bitwise_equal(got, want,
+                                 f"{path}/{combiner}/capacity {capacity}")
+
+
 class TestSsspTriangleInequality:
     @given(params=graph_params)
     @settings(max_examples=8, deadline=None)

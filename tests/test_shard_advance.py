@@ -23,6 +23,9 @@ job runs the whole file once per registered schedule.  The
 unconditionally, so even a single matrix leg covers every one.
 """
 import os
+import pathlib
+import subprocess
+import sys
 
 import numpy as np
 import jax
@@ -208,6 +211,47 @@ class TestMeshGlobalCompactCapacity:
                               num_blocks=4, compact=True)
         assert_bitwise_equal(sharded_delta_stepping(splan, 0), want,
                              f"compacted delta s{num_shards}")
+
+
+class TestShardedCompactLadder:
+    """Each shard runs the compaction rung its own active count needs,
+    inside ``shard_map``; the combining collective stays outside the
+    switch, so sharded push traversals keep the single-device bits."""
+
+    @pytest.mark.parametrize("num_shards", ALL_COUNTS)
+    def test_push_traversals_bitwise(self, num_shards):
+        # 1,500 vertices, 8,463 edges: push levels of 1 to 4,933 active
+        # edges span the rungs of (2200, 1100, 550) and the fallback
+        w = powerlaw_graph_dense(1500, avg_degree=6.0, seed=4)
+        g = Graph(CSR.from_dense(w))
+        splan = _build(g, num_shards, schedule="chunked", path="pure",
+                       num_blocks=8, compact=2200, delta="auto")
+        assert splan.template.compact_capacity == 2200
+        plan = build_advance(g, schedule="chunked", path="pure",
+                             num_blocks=8, compact=None, delta="auto")
+        assert_bitwise_equal(
+            sharded_bfs(splan, 0, direction="push"),
+            bfs(g, 0, plan=plan, direction="push"), f"bfs s{num_shards}")
+        assert_bitwise_equal(
+            sharded_delta_stepping(splan, 0, direction="push"),
+            delta_stepping(g, 0, plan=plan, direction="push"),
+            f"delta s{num_shards}")
+
+    @pytest.mark.skipif(_NDEV > 1, reason="the mesh cases run in-process")
+    def test_push_traversals_bitwise_on_four_host_devices(self):
+        # the parametrized test above on four forced host devices
+        here = pathlib.Path(__file__).resolve()
+        env = dict(os.environ,
+                   XLA_FLAGS="--xla_force_host_platform_device_count=4",
+                   JAX_PLATFORMS="cpu")
+        proc = subprocess.run(
+            [sys.executable, "-m", "pytest", "-q", "-p", "no:cacheprovider",
+             "-p", "no:xdist", "-p", "no:randomly",
+             f"{here}::TestShardedCompactLadder::test_push_traversals_bitwise"],
+            cwd=here.parent.parent, env=env, capture_output=True, text=True,
+            timeout=900)
+        assert proc.returncode == 0, proc.stdout[-4000:] + proc.stderr[-4000:]
+        assert "3 passed, 1 skipped" in proc.stdout, proc.stdout[-2000:]
 
 
 class TestShardedPagerank:

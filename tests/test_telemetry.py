@@ -1,6 +1,8 @@
 """Program telemetry: spans, the counter registry, compile attribution, the
 garbage-collection span, and the device scopes the trace metrics read."""
 import gc
+import json
+import pathlib
 import re
 import time
 
@@ -10,7 +12,7 @@ import numpy as np
 import pytest
 
 from repro.core import (adaptive_inspection_count, adaptive_partition,
-                        make_partition, measurement_count,
+                        compact_rungs, make_partition, measurement_count,
                         partition_build_count, telemetry, time_fn)
 from repro.core.work import WorkSpec
 from repro.sparse import CSR, Graph, build_advance
@@ -137,10 +139,16 @@ def test_bfs_loop_carries_every_traversal_scope(scale8):
     _, plan, _ = scale8
     found = _scopes_of(graph_mod._bfs_loop.lower(
         plan, jnp.int32(3), 256, "auto", False))
-    want = {"bfs.level", "push", "pull", "mask", "compact", "masked",
-            "windows", "scatter", "fixup", "frontier", "kernel"}
+    want = {"bfs.level", "push", "pull", "mask", "compact", "compact.r0",
+            "masked", "windows", "scatter", "fixup", "frontier", "kernel"}
     assert want <= found, want - found
     assert want <= set(telemetry.SCOPES)
+
+
+def test_every_compaction_rung_has_a_scope():
+    rungs = compact_rungs(2 ** 31 - 1)
+    assert {f"compact.r{k}" for k in range(len(rungs))} <= set(
+        telemetry.SCOPES)
 
 
 def test_pagerank_loop_carries_every_pagerank_scope(scale8):
@@ -181,3 +189,41 @@ def test_drivers_open_their_spans_and_the_inspector_its_own():
     graph_mod.pagerank(g, plan=plan, num_iters=2).block_until_ready()
     grew = {n for n in names if len(telemetry.recent_spans(n)) > before[n]}
     assert grew == set(names)
+
+
+# -- per-rung engagement from a trace (tools/rung_profile.py) ---------------
+
+def _rung_profile():
+    import importlib.util
+    path = pathlib.Path(__file__).resolve().parent.parent / "tools" \
+        / "rung_profile.py"
+    spec = importlib.util.spec_from_file_location("rung_profile", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def test_rung_profile_counts_each_stretch_of_a_rung_once():
+    rung_runs = _rung_profile().rung_runs
+    level = {"bfs.level", "push", "compact"}
+    ops = {"/device:TPU:0": [
+        ("a", 0, 1, level | {"compact.r3"}),
+        ("b", 1, 2, level | {"compact.r3", "windows"}),
+        ("copy", 2, 2.5, set()),                # added by XLA, no scope
+        ("b2", 2.5, 2.7, level | {"compact.r3", "scatter"}),
+        ("c", 2.7, 3, {"bfs.level", "mask"}),
+        ("d", 3, 4, level | {"compact.r3"}),
+        ("e", 4, 5, level | {"compact.r0"}),
+        ("f", 5, 6, level | {"compact.r0", "scatter"}),
+        ("g", 9, 10, level | {"compact.r1"}),      # outside the window
+    ]}
+    assert rung_runs(ops, 0, 8) == {"compact.r3": 2, "compact.r0": 1}
+    assert rung_runs(ops, 20, 30) == {}
+
+
+def test_rung_profile_reads_a_recorded_trace(capsys):
+    # a trace recorded before the ladder: it has a window and no rung
+    data = pathlib.Path(__file__).resolve().parent.parent / "bench" \
+        / "tests" / "data" / "v5e_scoped.xplane.pb"
+    assert _rung_profile().main([str(data)]) == 0
+    assert json.loads(capsys.readouterr().out) == {}

@@ -27,7 +27,8 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.core import ExecutionPath, Partition, Schedule, WorkSpec
+from repro.core import (ExecutionPath, Partition, Schedule, WorkSpec,
+                        compact_rungs, estimate_compact_capacity)
 from repro.core.execute import (native_chunk_tile_reduce,
                                 native_chunk_value_windows,
                                 native_compact_value_windows)
@@ -129,16 +130,30 @@ def test_chunk_walk_atoms_vmapped_over_lanes(graph_shapes):
              sds((LANES, A), jnp.bool_))
 
 
+#: The push plan's compaction ladder: 15 rungs from 15,728,640 slots down
+#: to 960, each walked as one chunk per 1,024 slots (at most ``C``), one
+#: chunk per grid step.
+RUNGS = compact_rungs(estimate_compact_capacity(A, 0.375))
+
+
 def test_chunk_walk_compact(graph_shapes):
+    """The top rung: 15,360 chunks."""
+    test_chunk_walk_compact_rungs(graph_shapes, 0)
+
+
+@pytest.mark.parametrize("rung", [len(RUNGS) // 2, len(RUNGS) - 1],
+                         ids=["middle", "bottom"])
+def test_chunk_walk_compact_rungs(graph_shapes, rung):
+    """A middle rung (122,880 slots, 120 chunks) and the bottom one (960
+    slots, one chunk)."""
     spec, part, sds = graph_shapes
-    capacity = A // 16
 
     def push(spec, part, vals, idx):
         return native_compact_value_windows(spec, part, lambda e: vals[e],
                                             idx, combiner="min")
 
     _compile(push, spec, part, sds((A,), jnp.float32),
-             sds((capacity,), jnp.int32))
+             sds((RUNGS[rung],), jnp.int32))
 
 
 def test_graph_server_step_fits_one_chip(graph_shapes):
@@ -147,6 +162,16 @@ def test_graph_server_step_fits_one_chip(graph_shapes):
     Lanes lead every per-edge array: vmapped the plain way, its gathers
     alone asked for over 30 GB.
     """
+    _check_server_step_fits(graph_shapes, "pull")
+
+
+def test_graph_server_push_step_fits_one_chip(graph_shapes):
+    """The push-direction serving step, whose lanes share one compaction
+    rung (the largest lane's), fits one chip's HBM too."""
+    _check_server_step_fits(graph_shapes, "push")
+
+
+def _check_server_step_fits(graph_shapes, direction):
     spec, part, sds = graph_shapes
     plan = AdvancePlan(
         spec=spec, src=sds((A,)), weight=sds((A,), jnp.float32), part=part,
@@ -166,7 +191,7 @@ def test_graph_server_step_fits_one_chip(graph_shapes):
     # the step takes its sizes from the batch; a two-vertex server makes it
     tiny = Graph(CSR(jnp.array([0, 1, 2]), jnp.array([1, 0]), jnp.ones(2),
                      (2, 2), 2))
-    step = GraphServer(tiny, lanes=LANES)._make_step()
+    step = GraphServer(tiny, lanes=LANES, direction=direction)._make_step()
     memory = _compile(step, plan, batch).memory_analysis()
     assert (memory.temp_size_in_bytes + memory.argument_size_in_bytes
             < HBM_BYTES)
