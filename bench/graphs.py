@@ -15,8 +15,9 @@ duplicate edges are removed.
 The endpoint bits are drawn on the device (JAX's threefry, the same bits on
 any backend); the permutation, the removal of loops and duplicates and the
 CSR are NumPy on the host, where sorting costs no compilation.  A weight is
-a hash of its edge's two ends and the seed, computed on the device, so both
-directions get the same one without a sort.
+a hash of its edge's two ends and the seed, computed on the device (in
+blocks of edges for a CSR kept on the host), so both directions get the same
+one without a sort.
 A configuration may state ``undirected_edges``, the distinct count its seed
 gives; a graph that differs from it is refused.
 """
@@ -64,17 +65,51 @@ def _mix32(x):
     return x ^ (x >> 16)
 
 
+#: Edges per block of :func:`edge_weights`: a block's temporaries take
+#: tens of MiB on the device, whatever the graph's size.
+WEIGHT_BLOCK = 1 << 22
+
+
 @jax.jit
-def edge_weights(offsets, cols, salt):
-    """Uniform float32 weights in [0, 1), one per undirected edge: a hash
-    of the edge's two ends and ``salt``, so both directions agree."""
-    e = cols.shape[0]
-    rows = jnp.searchsorted(offsets, jnp.arange(e, dtype=offsets.dtype),
-                            side="right") - 1
+def _weight_block(offsets, cols, start, salt):
+    """Weights of the edges ``start, start + 1, ...`` whose columns are
+    ``cols``; positions past the last edge give values nobody reads."""
+    pos = start + jnp.arange(cols.shape[0], dtype=offsets.dtype)
+    rows = jnp.searchsorted(offsets, pos, side="right") - 1
     lo = jnp.minimum(rows, cols).astype(jnp.uint32)
     hi = jnp.maximum(rows, cols).astype(jnp.uint32)
-    h = _mix32(lo ^ _mix32(hi ^ salt.astype(jnp.uint32)))
+    h = _mix32(lo ^ _mix32(hi ^ salt))
     return (h >> 8).astype(jnp.float32) * jnp.float32(2.0 ** -24)
+
+
+def edge_weights(offsets, cols, salt):
+    """Uniform float32 weights in [0, 1), one per undirected edge: a hash
+    of the edge's two ends and ``salt``, so both directions agree.
+
+    Where the CSR is on a device (JAX arrays), one pass there gives the
+    weights on that device.  Host (NumPy) arrays get them in blocks of
+    :data:`WEIGHT_BLOCK` edges, computed on the default device (the last
+    block padded, so one program serves every block) and each brought back
+    to the host: the device holds the row offsets and one block, never an
+    array of all the edges.  Every weight depends on its own edge alone, so
+    the blocks give the bits one pass would.
+    """
+    salt = jnp.uint32(salt)
+    if isinstance(cols, jax.Array):
+        return _weight_block(offsets, cols, jnp.zeros((), offsets.dtype), salt)
+    e = cols.shape[0]
+    out = np.empty(e, np.float32)
+    size = max(1, min(WEIGHT_BLOCK, e))
+    offsets = jnp.asarray(offsets)
+    for start in range(0, e, size):
+        part = cols[start:start + size]
+        if part.size < size:
+            part = np.concatenate(
+                [part, np.zeros(size - part.size, part.dtype)])
+        w = _weight_block(offsets, jnp.asarray(part),
+                          jnp.asarray(start, offsets.dtype), salt)
+        out[start:start + size] = np.asarray(w)[:min(size, e - start)]
+    return out
 
 
 def _symmetric_csr(und: np.ndarray, scale: int):

@@ -11,6 +11,12 @@ sits in a file of its own, found by the name ``BENCHMARK.json`` gives it:
 * ``bench/metrics/<metric>.py``: a reader with ``read(run) -> float | None``
   for each end-to-end and per-layer metric.
 
+A cell's ``chips`` decide where its graph lives and which plan is built: on
+one chip the graph goes to the default device and ``build_advance`` plans
+it; on n chips the program gets the host arrays and ``build_sharded_advance``
+plans them over its own graph mesh of n devices.  The kinds call the same
+drivers, which route on the plan's type.
+
 A run is a new process: its set-up is everything from the start of the
 process to the first timed call, and nothing compiles inside the window.
 The window runs the traffic's calls back to back in whole cycles of its
@@ -161,6 +167,62 @@ def window_done(elapsed: float, cycles: int, seconds: float) -> bool:
     return elapsed * (cycles + 1) / cycles > seconds
 
 
+def place_graph(offsets, cols, salt, chips: int):
+    """The program's ``Graph`` of the host CSR, with its weights: on the
+    default device for one chip; for several, as host arrays, which the
+    sharded inspector splits over the mesh (the weights computed in blocks,
+    so no array of all the edges lands on a chip)."""
+    import jax
+    import jax.numpy as jnp
+    from bench import graphs
+    from repro.sparse import CSR, Graph
+
+    n, e = offsets.size - 1, cols.size
+    if chips == 1:
+        offsets, cols = jnp.asarray(offsets), jnp.asarray(cols)
+    weights = graphs.edge_weights(offsets, cols, salt)
+    graph = Graph(CSR(offsets, cols, weights, (n, n), e))
+    return jax.block_until_ready(graph)
+
+
+def build_plan(graph, chips: int, options: dict):
+    """The inspector's plan for ``chips`` chips, built to the end, and the
+    devices it lives on: ``build_advance`` on the default device for one
+    chip, ``build_sharded_advance`` over the program's own graph mesh of
+    the first ``chips`` devices for several."""
+    import jax
+    from repro.sparse import build_advance, build_sharded_advance
+
+    if chips == 1:
+        plan = jax.block_until_ready(build_advance(graph, **options))
+        return plan, [jax.devices()[0]]
+    from repro.launch.mesh import make_graph_mesh
+    mesh = make_graph_mesh(chips)
+    plan = build_sharded_advance(graph, mesh, **options)
+    jax.block_until_ready(plan.data())
+    return plan, list(mesh.devices.flat)
+
+
+def describe_plan(plan) -> str:
+    """The plan's choices, for the log."""
+    one = getattr(plan, "template", plan)    # a sharded plan's shard 0
+    text = (f"pull={one.schedule.value}/{one.path.value} "
+            f"push={one.push_schedule.value}/{one.push_path.value} "
+            f"direction_threshold={one.direction_threshold!r} "
+            f"compact_capacity={one.compact_capacity}")
+    if one is not plan:
+        text += (f" shards={plan.num_shards} "
+                 f"shard_schedule={plan.shard_schedule}")
+    return text
+
+
+def memory_peaks(devices) -> list[int]:
+    """``peak_bytes_in_use`` of each device (0 where the backend keeps no
+    statistics)."""
+    return [int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+            for d in devices]
+
+
 def run_cell(root: pathlib.Path, name: str, *, seed: int, seconds: float,
              trace: bool, t_start: float, device: dict) -> dict:
     """Set up, measure and check one cell; returns the result line."""
@@ -176,7 +238,6 @@ def run_cell(root: pathlib.Path, name: str, *, seed: int, seconds: float,
 def _run_cell(root, name, *, seed, seconds, trace, t_start, device,
               counter) -> dict:
     import jax
-    import jax.numpy as jnp
     import numpy as np
 
     from bench import graphs
@@ -186,33 +247,22 @@ def _run_cell(root, name, *, seed, seconds, trace, t_start, device,
     cfg, mix = cell.config, cell.mix
     peaks = peaks_for(device["kind"], root / "bench" / "peaks.json")
     traffic_kind = load_kind(root, mix["kind"])
-    from repro.sparse import CSR, Graph, build_advance
-
     with _span("bench.generate"):
         t = time.perf_counter()
         host_offsets, host_cols, salt = graphs.generate(
             cfg, cfg["graph_seed"])
-        n, e = host_offsets.size - 1, host_cols.size
-        offsets, cols = jnp.asarray(host_offsets), jnp.asarray(host_cols)
-        graph = Graph(CSR(offsets, cols,
-                          graphs.edge_weights(offsets, cols, salt),
-                          (n, n), e))
-        jax.block_until_ready(graph)
-        del offsets, cols
+        graph = place_graph(host_offsets, host_cols, salt, cell.chips)
+    n, e = graph.num_vertices, graph.num_edges
     degrees = np.diff(host_offsets)
     _log(f"generate_s={time.perf_counter() - t!r} vertices={n} "
          f"directed_edges={e} max_degree={int(degrees.max())}")
     traffic = traffic_kind(mix, seed, degrees)
     with _span("bench.plan"):
         t = time.perf_counter()
-        plan = build_advance(graph, **{**cfg["plan"], **mix.get("plan", {})})
-        jax.block_until_ready(plan)
+        plan, devices = build_plan(
+            graph, cell.chips, {**cfg["plan"], **mix.get("plan", {})})
         plan_build_s = time.perf_counter() - t
-    _log(f"plan_build_s={plan_build_s!r} pull={plan.schedule.value}/"
-         f"{plan.path.value} push={plan.push_schedule.value}/"
-         f"{plan.push_path.value} direction_threshold="
-         f"{plan.direction_threshold!r} compact_capacity="
-         f"{plan.compact_capacity}")
+    _log(f"plan_build_s={plan_build_s!r} {describe_plan(plan)}")
     with _span("bench.warm_up"):
         t = time.perf_counter()
         traffic.warm_up(graph, plan)
@@ -246,11 +296,11 @@ def _run_cell(root, name, *, seed, seconds, trace, t_start, device,
     compiles = counter.count - compiles0
     call_s = np.diff(call_ends, prepend=0.0)
     _log(f"call_s={[float(x) for x in call_s]!r}")
-    stats = jax.devices()[0].memory_stats() or {}
-    device = {**device,
-              "memory_peak_bytes": int(stats.get("peak_bytes_in_use", 0))}
+    per_chip = memory_peaks(devices)
+    device = {**device, "memory_peak_bytes": max(per_chip),
+              "memory_peak_bytes_per_chip": per_chip}
     _log(f"window_s={window_s!r} programs_in_window={compiles} "
-         f"memory_peak_bytes={device['memory_peak_bytes']}")
+         f"memory_peak_bytes_per_chip={per_chip}")
 
     # the program's state goes before the reference runs
     work = traffic.collect()
@@ -262,6 +312,7 @@ def _run_cell(root, name, *, seed, seconds, trace, t_start, device,
         from bench.trace import find_xplane, reduce_trace
         run.trace = reduce_trace(find_xplane(logdir))
         shutil.rmtree(logdir)
+        _log(f"trace_chips={run.trace.chips}")
         device["busy_s"] = run.trace.busy_s
         device["window_s"] = run.trace.window_s
 

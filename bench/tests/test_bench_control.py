@@ -13,7 +13,7 @@ from bench import control  # noqa: E402
 
 
 @pytest.mark.parametrize("cell", ["kron-s20.bfs", "urand-s20.bfs",
-                                  "kron-s20.pagerank"])
+                                  "kron-s20.pagerank", "urand-s20.pagerank"])
 def test_control_fails_the_check(root, cell):
     for line in control.readings(root, cell, [3, 2 ** 31 + 5]):
         (name, c), = line["compared"].items()

@@ -4,6 +4,7 @@ from __future__ import annotations
 import pathlib
 import sys
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -24,9 +25,7 @@ def _cfg(generator: str, scale: int) -> dict:
 
 def _host(cfg: dict, seed: int):
     offsets, cols, salt = graphs.generate(cfg, seed)
-    weights = graphs.edge_weights(jnp.asarray(offsets), jnp.asarray(cols),
-                                  salt)
-    return offsets, cols, np.asarray(weights)
+    return offsets, cols, graphs.edge_weights(offsets, cols, salt)
 
 
 @pytest.mark.parametrize("generator", graphs.GENERATORS)
@@ -68,6 +67,39 @@ def test_stated_edge_count_is_checked():
     graphs.generate({**cfg, "undirected_edges": edges // 2}, 4)
     with pytest.raises(ValueError, match="distinct edges"):
         graphs.generate({**cfg, "undirected_edges": edges // 2 + 1}, 4)
+
+
+def _numpy_weights(offsets, cols, salt):
+    """The weights' hash in NumPy's uint32 arithmetic, edge by edge."""
+    def mix(x):
+        x = x ^ (x >> np.uint32(16))
+        x = x * np.uint32(0x7FEB352D)
+        x = x ^ (x >> np.uint32(15))
+        x = x * np.uint32(0x846CA68B)
+        return x ^ (x >> np.uint32(16))
+    rows = np.repeat(np.arange(offsets.size - 1), np.diff(offsets))
+    lo = np.minimum(rows, cols).astype(np.uint32)
+    hi = np.maximum(rows, cols).astype(np.uint32)
+    h = mix(lo ^ mix(hi ^ np.uint32(salt)))
+    return (h >> np.uint32(8)).astype(np.float32) * np.float32(2.0 ** -24)
+
+
+@pytest.mark.parametrize("generator", graphs.GENERATORS)
+def test_blocked_weights_are_the_whole_arrays_bits(generator, monkeypatch):
+    offsets, cols, salt = graphs.generate(_cfg(generator, 9), 2 ** 31 + 3)
+    e = cols.size
+    assert e < graphs.WEIGHT_BLOCK
+    whole = graphs.edge_weights(offsets, cols, salt)        # one block
+    monkeypatch.setattr(graphs, "WEIGHT_BLOCK", 1000)
+    assert e % 1000 and e > 3 * 1000
+    blocked = graphs.edge_weights(offsets, cols, salt)
+    assert isinstance(blocked, np.ndarray) and blocked.dtype == np.float32
+    np.testing.assert_array_equal(blocked, whole)
+    np.testing.assert_array_equal(whole, _numpy_weights(offsets, cols, salt))
+    on_device = graphs.edge_weights(jnp.asarray(offsets), jnp.asarray(cols),
+                                    salt)
+    assert isinstance(on_device, jax.Array)
+    np.testing.assert_array_equal(np.asarray(on_device), whole)
 
 
 def _path_plus_triangle():

@@ -41,8 +41,8 @@ def _expected(root, cell, key):
 
 
 @pytest.mark.parametrize("cell", ["kron-s20.bfs", "kron-s20.pagerank",
-                                  "urand-s20.bfs", "kron-s20.bfs-4roots",
-                                  "kron-s20.reach"])
+                                  "urand-s20.bfs", "urand-s20.pagerank",
+                                  "kron-s20.bfs-4roots", "kron-s20.reach"])
 def test_cell_runs_and_reports_its_end_to_end_metrics(root, cell, capsys):
     result = _run(root, cell)
     assert list(result)[:5] == RESULT_KEYS
@@ -54,7 +54,9 @@ def test_cell_runs_and_reports_its_end_to_end_metrics(root, cell, capsys):
     for m in result["metrics"].values():
         assert m["value"] > 0 and set(m) == {"value", "unit"}
     assert set(result["device"]) == {"platform", "kind", "count",
-                                     "memory_peak_bytes"}
+                                     "memory_peak_bytes",
+                                     "memory_peak_bytes_per_chip"}
+    assert len(result["device"]["memory_peak_bytes_per_chip"]) == 1
     err = capsys.readouterr().err.strip().splitlines()
     assert err[-1].startswith("bench compared ")
     assert re.search(r"limit=", err[-1])
@@ -91,6 +93,27 @@ def test_run_cell_leaves_the_process_as_it_found_it(root):
     assert jax.config.jax_compilation_cache_dir == cache_dir
     assert dict(os.environ) == env
     assert gc.get_freeze_count() == 0
+
+
+class _FakeDevice:
+    def __init__(self, stats):
+        self.stats = stats
+
+    def memory_stats(self):
+        return self.stats
+
+
+def test_memory_peak_is_the_fullest_of_the_cells_devices(root, monkeypatch):
+    devices = [_FakeDevice({"peak_bytes_in_use": b, "bytes_in_use": 1})
+               for b in (7, 300, 12, 299)]
+    assert harness.memory_peaks(devices) == [7, 300, 12, 299]
+    assert harness.memory_peaks([_FakeDevice(None)]) == [0]
+    build = harness.build_plan
+    monkeypatch.setattr(harness, "build_plan",
+                        lambda *a: (build(*a)[0], devices))
+    device = _run(root, "kron-s20.pagerank")["device"]
+    assert device["memory_peak_bytes"] == 300
+    assert device["memory_peak_bytes_per_chip"] == [7, 300, 12, 299]
 
 
 def test_run_py_refuses_without_a_tpu():
@@ -162,6 +185,7 @@ def _alter_pagerank_answer(monkeypatch):
     ("urand-s20.bfs", _break_bfs_step),
     ("kron-s20.pagerank", _break_pagerank_step),
     ("kron-s20.pagerank", _alter_pagerank_answer),
+    ("urand-s20.pagerank", _break_pagerank_step),
 ])
 def test_broken_timed_path_is_not_correct(root, cell, fault, monkeypatch):
     fault(monkeypatch)
